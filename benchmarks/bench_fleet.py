@@ -408,8 +408,9 @@ def _run_mega_bench(fast: bool, seed: int, tag: str, kw: dict) -> None:
 def _run_megax_bench(fast: bool, seed: int, tag: str) -> None:
     """`{tag}.megax.*`: the compiled (jax) bulk-scan backend vs numpy.
 
-    Both backends drive the identical structural event loop (totals
-    anchored to <=1e-9 in tests/test_mega.py), so the rows isolate the
+    Both backends drive the identical structural event loop (energy
+    anchored to <=1e-9, carbon to the f32 kernel's CARBON_REL, in
+    tests/test_mega.py), so the rows isolate the
     BULK-SCAN phases -- big-gap scans, deferred billing, energy
     segment-sums, and the carbon trapezoid integral -- which is where
     the jit-compiled array programs (and the segment_trapz kernel) do

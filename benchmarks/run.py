@@ -17,6 +17,9 @@ def main() -> None:
                             bench_kernels, bench_paper_tables,
                             bench_roofline, bench_serving)
     from benchmarks.common import print_csv
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     fast = "--fast" in sys.argv
     print("#" * 72)

@@ -14,10 +14,12 @@ wall-clock comparison (the frontiers are identical point-for-point).
 """
 import argparse
 
+from repro.compile_cache import use_compile_cache
 from repro.fleet.planner import pinned_day_axes, pinned_day_base, plan_fleet
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true",
                     help="6 h horizon + numpy backend")

@@ -22,20 +22,27 @@ The closer repeats one day on the compiled backend
 joules, bulk arithmetic jit-compiled -- then sweeps a batch of seeded
 days through run_mega_sweep so the compiles amortize across points.
 
-Run:  PYTHONPATH=src JAX_PLATFORMS=cpu python examples/mega_day.py
+Run:  PYTHONPATH=src python examples/mega_day.py
+
+On a TPU the compiled backend runs on the chip (its metering kernel
+compiled by Mosaic); anywhere else JAX falls back to its CPU backend
+with the kernel interpreted -- same arithmetic, no device timings.
 """
 import time
 
+from repro.compile_cache import use_compile_cache
 from repro.core.scheduler import Breakeven
 from repro.fleet import (flash_crowd, make_trace, mixed_fleet_scenario,
                          product_launch, regional_outage, run_fleet,
                          run_mega, run_mega_sweep)
+from repro.kernels.segment_trapz import CARBON_REL
 
 SEED = 100
 FLEET = "200xh100+200xa100+200xl40s"
 
 
 def main() -> None:
+    use_compile_cache()
     # -- the anchor: same day, both simulators, same joules ------------
     t0 = time.perf_counter()
     ref = run_fleet(mixed_fleet_scenario(Breakeven, "warm-first",
@@ -91,8 +98,9 @@ def main() -> None:
               f" {res.carbon_kg:8.1f} kgCO2e"
               f"   bulk {bulk:5.1f} s   wall {wall:5.1f} s")
     assert results["jax"].requests == results["numpy"].requests
+    # carbon runs through the f32 metering kernel: its derived bound
     assert abs(results["jax"].carbon_kg - results["numpy"].carbon_kg) \
-        <= 1e-9 * results["numpy"].carbon_kg
+        <= CARBON_REL * results["numpy"].carbon_kg
 
     # -- sweep: compile once, run the batch hot ------------------------
     n_pts = 8

@@ -11,6 +11,7 @@ Run:  PYTHONPATH=src python examples/serve_parking.py
 """
 import jax
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_reduced
 from repro.core import H100, QWEN25_7B_MEASURED
 from repro.core.scheduler import AlwaysOn, Breakeven
@@ -20,6 +21,7 @@ from repro.serving import ModelManager, ServingEngine, SimClock
 
 
 def main() -> None:
+    use_compile_cache()
     cfg = get_reduced("qwen2-5-7b")
     params = materialize(build_param_specs(cfg), jax.random.PRNGKey(0))
     # one warm engine reused across cold starts: in production the load

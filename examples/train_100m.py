@@ -10,6 +10,7 @@ import dataclasses
 
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.models.config import dense_lm
 from repro.models.model import RunFlags
 from repro.training.optimizer import AdamWConfig
@@ -24,6 +25,7 @@ CONFIG = dataclasses.replace(CONFIG, param_dtype=None or CONFIG.param_dtype)
 
 
 def main() -> None:
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--batch", type=int, default=8)

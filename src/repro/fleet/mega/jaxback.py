@@ -25,20 +25,23 @@ programs behind the ``_NumpyBulk`` seam:
     seconds are two ``jax.ops.segment_sum`` calls at finalize.
   * **carbon integration** -- the power-timeline x ``CarbonTrace``
     trapezoid integral runs through the ``kernels/segment_trapz``
-    Pallas kernel (jnp reference under interpret mode, see
-    ``kernels/ops.py``), with per-device attribution one segment-sum
-    away; the hourly cumulative timeline is the same prefix-integral
-    evaluated at bin boundaries under ``lax.map``.
+    ``fused_meter`` Pallas kernel (compiled on a TPU, interpreted
+    elsewhere, see ``kernels/ops.py``), with per-device attribution one
+    segment-sum away; the hourly cumulative timeline adds the partial
+    integrals of the segments that straddle each bin boundary.
 
-Everything is float64 (the fleet accounting convention) via the
-``jax.experimental.enable_x64`` scope, which is thread-local and does
-not disturb the f32 kernel tests elsewhere in the repo.  All array
-programs pad to power-of-two sizes with masked/zero-weight tails, so a
-sweep over many same-shaped days reuses every compiled program.
+Everything outside the kernel is float64 (the fleet accounting
+convention) via the ``jax.enable_x64`` scope, which is thread-local and
+does not disturb the f32 kernel tests elsewhere in the repo.  The
+kernel itself runs in f32 (Mosaic has no 64-bit types) on the in-period
+part of each carbon integral only.  All array programs pad to
+power-of-two sizes with masked/zero-weight tails, so a sweep over many
+same-shaped days reuses every compiled program.
 
 Both backends drive the identical event loop and see identical calls,
-so requests/cold starts are equal and float totals (energy, carbon)
-agree to <=1e-9 relative -- pinned in ``tests/test_mega.py``.
+so requests/cold starts are equal, energy and dollars agree to <=1e-9
+relative, and carbon agrees within the kernel's f32 bound
+(``segment_trapz.CARBON_REL``) -- pinned in ``tests/test_mega.py``.
 """
 from __future__ import annotations
 
@@ -54,7 +57,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import enable_x64
 
 from repro.fleet.carbon import CarbonTrace
 from repro.fleet.fleetsim import DAY, FleetResult
@@ -190,26 +192,27 @@ def _carbon_fused(a, b, w, dev, bucket, pseg, pk, pw, kt, kv, cum, tbr, *,
     return per_dev, full / _J_PER_KWH
 
 
-def _prefix_rows(kt: jnp.ndarray, kv: jnp.ndarray, cum: jnp.ndarray,
-                 per: jnp.ndarray, t: jnp.ndarray) -> jnp.ndarray:
-    """``F_g(t)`` for stacked trace tables: kt/kv/cum [G, K] (rows
-    padded by repeating the last knot), per [G], t [T] -> [G, T].  The
-    row-wise twin of ``_prefix_fn`` (same closed form, compare-and-sum
-    lookup instead of a shared searchsorted)."""
-    total = cum[:, -1:]
-    k = jnp.floor(t[None, :] / per[:, None])
-    p = t[None, :] - k * per[:, None]
-    j = jnp.sum((kt[:, None, :] <= p[:, :, None]).astype(jnp.int32),
-                axis=2) - 1
-    j = jnp.clip(j, 0, kt.shape[1] - 2)
+def _prefix_at(kt: jnp.ndarray, kv: jnp.ndarray, cum: jnp.ndarray,
+               per: jnp.ndarray, g: jnp.ndarray, t: jnp.ndarray
+               ) -> jnp.ndarray:
+    """``F_g(t)`` per point for stacked trace tables: kt/kv/cum [G, K]
+    (rows padded by repeating the last knot), per [G], g and t [P] ->
+    [P].  The per-row twin of ``_prefix_fn`` (same closed form,
+    compare-and-sum lookup instead of a shared searchsorted)."""
+    ktg, kvg, cumg, pg = kt[g], kv[g], cum[g], per[g]
+    k = jnp.floor(t / pg)
+    p = t - k * pg
+    j = jnp.sum((ktg <= p[:, None]).astype(jnp.int32), axis=1) - 1
+    j = jnp.clip(j, 0, kt.shape[1] - 2)[:, None]
     take = jnp.take_along_axis
-    kt_j = take(kt, j, axis=1)
-    kv_j = take(kv, j, axis=1)
-    span = take(kt, j + 1, axis=1) - kt_j
+    kt_j = take(ktg, j, axis=1)[:, 0]
+    kv_j = take(kvg, j, axis=1)[:, 0]
+    span = take(ktg, j + 1, axis=1)[:, 0] - kt_j
     dt = p - kt_j
-    v_p = kv_j + (take(kv, j + 1, axis=1) - kv_j) * dt \
+    v_p = kv_j + (take(kvg, j + 1, axis=1)[:, 0] - kv_j) * dt \
         / jnp.where(span > 0, span, 1.0)
-    return k * total + take(cum, j, axis=1) + dt * (kv_j + v_p) * 0.5
+    return (k * cumg[:, -1] + take(cumg, j, axis=1)[:, 0]
+            + dt * (kv_j + v_p) * 0.5)
 
 
 @functools.partial(jax.jit, static_argnames=("n_dev", "nb", "n_tier"))
@@ -217,22 +220,23 @@ def _meter_fused(keys, a, b, dt, pw, g, bucket, tdev, pseg, pk, pwp,
                  kts, kvs, cums, pers, tbr, *,
                  n_dev: int, nb: int, n_tier: int):
     """The whole metering reduction in one compiled program fed by ONE
-    fused kernel pass (``ops.fused_meter``) over the raw charge log:
+    metering pass (``ops.fused_meter``) over the raw charge log:
 
       * per-(device, state) joules/seconds -- same ``segment_sum`` of
-        the same ``w * dt`` products as ``_energy_segsum``, so the
+        the same f64 ``w * dt`` products as ``_energy_segsum``, so the
         energy/billing numbers (and the 0.0-USD engine anchors built
         on them) are bit-identical to the unfused path;
       * per-device carbon + the hourly cumulative timeline -- same
         end-bin + straddle-correction decomposition as
         ``_carbon_fused``, but over raw log entries (uncoalesced) and
         with every zone's trace in one stacked-table launch instead of
-        one compiled call per zone group;
+        one compiled call per zone group; the straddle input
+        ``F_g(a)`` is evaluated in f64 at the straddle pairs only;
       * per-tier billed seconds -- a third segment-sum of the SAME
-        kernel output, free at this point (in mega scope every metered
-        state is powered-on, so raw seconds == billed seconds).
+        seconds lane (in mega scope every metered state is
+        powered-on, so raw seconds == billed seconds).
     """
-    e, s, c, fa = ops.fused_meter(a, b, dt, pw, g, kts, kvs, cums, pers)
+    e, s, c = ops.fused_meter(a, b, dt, pw, g, kts, kvs, cums, pers)
     ej = jax.ops.segment_sum(e, keys, num_segments=n_dev * 3)
     ds = jax.ops.segment_sum(s, keys, num_segments=n_dev * 3)
     dev = keys // 3
@@ -240,10 +244,10 @@ def _meter_fused(keys, a, b, dt, pw, g, bucket, tdev, pseg, pk, pwp,
     tier_s = jax.ops.segment_sum(s, tdev[dev], num_segments=n_tier)
     full = jnp.cumsum(jax.ops.segment_sum(c, bucket, num_segments=nb))
     if nb > 1:
-        Fb = _prefix_rows(kts, kvs, cums, pers, tbr)      # [G, nb-1]
         pg = g[pseg]
-        corr = jax.ops.segment_sum(pwp * (Fb[pg, pk] - fa[pseg]), pk,
-                                   num_segments=nb - 1)
+        part = (_prefix_at(kts, kvs, cums, pers, pg, tbr[pk])
+                - _prefix_at(kts, kvs, cums, pers, pg, a[pseg]))
+        corr = jax.ops.segment_sum(pwp * part, pk, num_segments=nb - 1)
         full = full.at[:nb - 1].add(corr)
     return ej, ds, per_dev, tier_s, full / _J_PER_KWH
 
@@ -317,7 +321,7 @@ class _JaxBulk:
                     continue
                 L = _pow2(ms.n)
                 buckets.setdefault(L, []).append((mid, float(T), ms.arr))
-        with enable_x64():
+        with jax.enable_x64(True):
             for L, grp in buckets.items():
                 rows = _pow2(len(grp), lo=8)
                 mat = np.zeros((rows, L), dtype=np.float64)
@@ -397,7 +401,7 @@ class _JaxBulk:
     def finalize(self, segs, fleet_segments, trace: CarbonTrace,
                  horizon: float, dev_traces=None,
                  tiers=None) -> "megasim._Fin":
-        with enable_x64():
+        with jax.enable_x64(True):
             if self.fused:
                 (energy_j, dur_s, carbon_dev, timeline,
                  tier_billed) = self._finalize_fused(trace, horizon,
@@ -639,14 +643,9 @@ def compiled_program_count() -> int:
     have compiled so far (summed jit-cache sizes).  The batched planner
     reports the delta per sweep: shared-shape grouping shows up as a
     compile count that stays flat while the point count grows."""
-    total = 0
-    for fn in (_nextbig_rows, _bill_gather, _energy_segsum,
-               _carbon_fused, _meter_fused):
-        try:
-            total += fn._cache_size()
-        except Exception:      # cache API moved: count as unknown/0
-            pass
-    return total
+    return sum(fn._cache_size() for fn in (
+        _nextbig_rows, _bill_gather, _energy_segsum, _carbon_fused,
+        _meter_fused))
 
 
 # ---------------------------------------------------------------------------
@@ -680,7 +679,7 @@ def _sample_group(keys: np.ndarray, rate_fn, rate_max: float,
         keep = (jnp.arange(n_max) < cnt) & (u < rate_fn(t))
         return jnp.sort(jnp.where(keep, t, jnp.inf)), keep.sum()
 
-    with enable_x64():
+    with jax.enable_x64(True):
         ts, counts = jax.jit(jax.vmap(one))(jnp.asarray(keys))
     return np.asarray(ts), np.asarray(counts)
 

@@ -434,11 +434,8 @@ def _batched_points(grid, backend: str,
 
 
 def _compile_count() -> int:
-    try:
-        from repro.fleet.mega import jaxback
-        return jaxback.compiled_program_count()
-    except Exception:
-        return 0
+    from repro.fleet.mega import jaxback
+    return jaxback.compiled_program_count()
 
 
 def plan_fleet(base_scenario: FleetScenario, axes: PlanAxes, *,

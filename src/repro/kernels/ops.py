@@ -1,16 +1,17 @@
 """Public jit'd wrappers for the Pallas kernels.
 
 One switch (``use_pallas``) selects the kernel or the pure-jnp reference;
-the serving engine and benchmarks call through here so swapping in the
-TPU kernels is a one-line config change.  On this CPU container kernels
-run with interpret=True (Python-executed kernel bodies, same arithmetic);
-on TPU set REPRO_PALLAS_INTERPRET=0.
+the serving engine and benchmarks call through here.  Kernels compile
+for the chip when the default backend is a TPU and run in Pallas
+interpret mode (same kernel body, same f32 arithmetic) anywhere else;
+the choice is made at call time, so importing this module starts no
+backend.
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
@@ -20,7 +21,10 @@ from repro.kernels.rglru_scan import rglru_scan as _rglru_pl
 from repro.kernels.segment_trapz import fused_meter as _fused_pl
 from repro.kernels.segment_trapz import segment_trapz as _trapz_pl
 
-INTERPRET = os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
+
+def _interpret() -> bool:
+    """Interpret kernels unless the default backend is a TPU."""
+    return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
@@ -28,20 +32,20 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     use_pallas: bool = True) -> jnp.ndarray:
     if use_pallas:
         return _flash_pl(q, k, v, causal=causal, window=window,
-                         interpret=INTERPRET)
+                         interpret=_interpret())
     return ref.flash_attention_ref(q, k, v, causal=causal, window=window)
 
 
 def decode_attention(q, k, v, length, *, use_pallas: bool = True
                      ) -> jnp.ndarray:
     if use_pallas:
-        return _decode_pl(q, k, v, length, interpret=INTERPRET)
+        return _decode_pl(q, k, v, length, interpret=_interpret())
     return ref.decode_attention_ref(q, k, v, length)
 
 
 def rglru_scan(a, b, h0, *, use_pallas: bool = True) -> jnp.ndarray:
     if use_pallas:
-        return _rglru_pl(a, b, h0, interpret=INTERPRET)
+        return _rglru_pl(a, b, h0, interpret=_interpret())
     return ref.rglru_scan_ref(a, b, h0)
 
 
@@ -50,31 +54,23 @@ def segment_trapz(a, b, w, kt, kv, cum, *, period: float,
     """Per-segment trapezoid integrals of a periodic piecewise-linear
     curve (the carbon-integration primitive; see segment_trapz.py).
 
-    ``use_pallas=None`` (the default) picks the kernel on real hardware
-    and the jnp reference when kernels would run interpreted: unlike
-    the attention kernels above (called on a handful of activations per
-    step), this one streams millions of metered segments per fleet day,
-    where a Python-interpreted kernel body would dominate the very
-    bulk-scan phase it exists to accelerate.
+    ``use_pallas=None`` (the default) picks the kernel on a TPU and the
+    jnp reference elsewhere.  This standalone kernel is off the fleet
+    backend's main path (``fused_meter`` is on it) and does not lower
+    for the chip: there it fails loudly instead of falling back.
     """
     if use_pallas is None:
-        use_pallas = not INTERPRET
+        use_pallas = not _interpret()
     if use_pallas:
         return _trapz_pl(a, b, w, kt, kv, cum, period=period,
-                         interpret=INTERPRET)
+                         interpret=_interpret())
     return ref.segment_trapz_ref(a, b, w, kt, kv, cum, period=period)
 
 
-def fused_meter(a, b, dt, w, g, kt, kv, cum, periods, *,
-                use_pallas: Optional[bool] = None):
-    """Fused metering pass: per charge-log entry energy / billed
-    seconds / carbon increment / start-prefix in one launch (see
-    segment_trapz.fused_meter).  Same ``use_pallas=None`` policy as
-    ``segment_trapz``: this streams the whole metered charge log, so
-    interpret-mode containers take the jnp reference."""
-    if use_pallas is None:
-        use_pallas = not INTERPRET
-    if use_pallas:
-        return _fused_pl(a, b, dt, w, g, kt, kv, cum, periods,
-                         interpret=INTERPRET)
-    return ref.fused_meter_ref(a, b, dt, w, g, kt, kv, cum, periods)
+def fused_meter(a, b, dt, w, g, kt, kv, cum, periods):
+    """Metering pass over the charge log: per-entry energy, seconds and
+    carbon increment (see ``segment_trapz.fused_meter``).  Always the
+    kernel: compiled on a TPU, interpreted elsewhere, so CPU runs check
+    the arithmetic the chip runs."""
+    return _fused_pl(a, b, dt, w, g, kt, kv, cum, periods,
+                     interpret=_interpret())

@@ -84,13 +84,12 @@ def fused_meter_ref(a: jnp.ndarray, b: jnp.ndarray, dt: jnp.ndarray,
                     w: jnp.ndarray, g: jnp.ndarray,
                     kt: jnp.ndarray, kv: jnp.ndarray, cum: jnp.ndarray,
                     periods: jnp.ndarray):
-    """Fused metering pass (see ``segment_trapz.fused_meter``): per
-    charge-log entry emit energy ``w * dt``, seconds ``dt``, carbon
-    increment ``w * (F_g(b) - F_g(a))``, and ``F_g(a)``.  kt, kv, cum
-    are stacked ``[G, K]`` extended knot tables (rows padded by
-    repeating the last knot); g: [N] int32 selects each entry's row;
-    periods: [G].  Uses the same compare-and-sum knot lookup as the
-    kernel (row-wise tables rule out a shared ``searchsorted``)."""
+    """Metering pass (see ``segment_trapz.fused_meter``): per charge-log
+    entry emit energy ``w * dt``, seconds ``dt`` and carbon increment
+    ``w * (F_g(b) - F_g(a))``.  kt, kv, cum are stacked ``[G, K]``
+    extended knot tables (rows padded by repeating the last knot); g:
+    [N] int32 selects each entry's row; periods: [G].  Same closed
+    form as ``segment_trapz_ref``, row by row, in the inputs' dtype."""
     ktg = jnp.take(kt, g, axis=0)               # [N, K]
     kvg = jnp.take(kv, g, axis=0)
     cumg = jnp.take(cum, g, axis=0)
@@ -112,8 +111,7 @@ def fused_meter_ref(a: jnp.ndarray, b: jnp.ndarray, dt: jnp.ndarray,
         return (k * total + take(cumg, j, axis=1)[:, 0]
                 + d * (kv_j + v_p) * 0.5)
 
-    fa = prefix(a)
-    return w * dt, dt, w * (prefix(b) - fa), fa
+    return w * dt, dt, w * (prefix(b) - prefix(a))
 
 
 def rglru_scan_ref(a: jnp.ndarray, bx: jnp.ndarray,

@@ -116,8 +116,6 @@ def _trace_tables(trace):
 @pytest.mark.parametrize("n", [1, 17, 512, 2001])
 @pytest.mark.parametrize("shape_name", ["solar-duck", "wind-night", "flat"])
 def test_segment_trapz_sweep(n, shape_name):
-    from jax.experimental import enable_x64
-
     from repro.fleet.carbon import make_trace
 
     trace = make_trace(shape_name, 0.39)
@@ -128,7 +126,7 @@ def test_segment_trapz_sweep(n, shape_name):
     b = a + rng.uniform(0.0, 4 * 3600.0, n)
     w = rng.uniform(10.0, 700.0, n)
     want = np.array([trace.integral(x, y) * z for x, y, z in zip(a, b, w)])
-    with enable_x64():
+    with jax.enable_x64(True):
         args = [jnp.asarray(x) for x in (a, b, w, kt, kv, cum)]
         got_pl = np.asarray(ops.segment_trapz(
             *args, period=trace.period_s, use_pallas=True))
@@ -158,13 +156,11 @@ def test_segment_trapz_f32_kernel_matches_ref():
 
 
 def test_segment_trapz_zero_and_empty_segments():
-    from jax.experimental import enable_x64
-
     from repro.fleet.carbon import solar_duck
 
     trace = solar_duck(0.39)
     kt, kv, cum = _trace_tables(trace)
-    with enable_x64():
+    with jax.enable_x64(True):
         empty = ops.segment_trapz(
             jnp.zeros(0), jnp.zeros(0), jnp.zeros(0),
             jnp.asarray(kt), jnp.asarray(kv), jnp.asarray(cum),
@@ -179,11 +175,12 @@ def test_segment_trapz_zero_and_empty_segments():
 
 
 # ---------------------------------------------------------------------------
-# fused_meter: the single-pass metering kernel behind the mega jax
-# backend's fused finalize (energy segment-sum + per-tier billed seconds
-# + per-trace carbon trapezoid in ONE launch).  Oracle chain: Pallas
-# kernel == jnp reference == CarbonTrace.integral per entry, and the
-# energy/seconds outputs are BIT-identical to the unfused inputs.
+# fused_meter: the metering pass behind the mega jax backend's fused
+# finalize (energy segment-sum + per-tier billed seconds + per-trace
+# carbon trapezoid).  The kernel integrates each entry's in-period span
+# in f32; oracle chain: kernel == f64 jnp reference == CarbonTrace.integral
+# per entry within the derived bound CARBON_REL, and the energy/seconds
+# outputs are BIT-identical to the unfused inputs.
 # ---------------------------------------------------------------------------
 
 def _stacked_tables(traces):
@@ -201,15 +198,54 @@ def _stacked_tables(traces):
     return kt, kv, cum, per
 
 
+F32_U = 2.0 ** -24          # unit roundoff of the kernel's arithmetic
+
+
+def _carbon_error_factor(kv) -> float:
+    """``C`` of ``segment_trapz.fused_meter``'s derived bound
+    ``|dI| <= C * F32_U * I`` for a curve with knot values ``kv``:
+    ``kappa + TV/min + 1.5 * max|dkv|/min + 8``."""
+    v = np.asarray(kv, dtype=np.float64)
+    lo = float(v.min())
+    d = np.abs(np.diff(v))
+    return float(v.max() / lo + d.sum() / lo
+                 + 1.5 * d.max(initial=0.0) / lo + 8.0)
+
+
+def _exact_integral(trace, t0, t1):
+    """int_t0^t1 i(u) du in exact rational arithmetic: the oracle for
+    spans where the f64 prefix difference of ``CarbonTrace.integral``
+    itself cancels (sub-second segments at ~1e5 s)."""
+    import bisect
+    from fractions import Fraction as Fr
+
+    kt = [Fr(x) for x in trace._kt]
+    kv = [Fr(x) for x in trace._kv]
+    per = Fr(trace.period_s)
+    areas = [(kt[i + 1] - kt[i]) * (kv[i + 1] + kv[i]) / 2
+             for i in range(len(kt) - 1)]
+
+    def F(t):
+        k = t // per
+        p = t - k * per
+        j = min(max(bisect.bisect_right(kt, p) - 1, 0), len(kt) - 2)
+        span = kt[j + 1] - kt[j]
+        v_p = kv[j] + (kv[j + 1] - kv[j]) * (p - kt[j]) / span
+        return k * sum(areas) + sum(areas[:j]) + (p - kt[j]) * (kv[j] + v_p) / 2
+
+    return float(F(Fr(t1)) - F(Fr(t0)))
+
+
 @pytest.mark.parametrize("n", [1, 33, 1024, 3001])
 @pytest.mark.parametrize("seed", [0, 7])
 def test_fused_meter_sweep(n, seed):
     """Multi-trace entries crossing knots, midnight, and whole periods:
-    carbon matches the Python integral, energy/seconds are exact
-    pass-throughs, fa is the prefix integral at each start."""
-    from jax.experimental import enable_x64
-
+    carbon matches the f64 reference and the Python integral within the
+    kernel's bound, energy/seconds are exact pass-throughs, and the f64
+    straddle prefix is the integral from 0 to each start."""
     from repro.fleet.carbon import make_trace
+    from repro.fleet.mega.jaxback import _prefix_at
+    from repro.kernels.segment_trapz import CARBON_REL
 
     traces = [make_trace(s, 0.39) for s in
               ("solar-duck", "wind-night", "flat")]
@@ -224,30 +260,69 @@ def test_fused_meter_sweep(n, seed):
                        for gi, x, y, z in zip(g, a, b, w)])
     want_fa = np.array([traces[gi].integral(0.0, x)
                         for gi, x in zip(g, a)])
-    with enable_x64():
+    with jax.enable_x64(True):
         args = [jnp.asarray(x) for x in (a, b, dt, w, g, kt, kv, cum, per)]
-        got_pl = [np.asarray(o) for o in
-                  ops.fused_meter(*args, use_pallas=True)]
-        got_ref = [np.asarray(o) for o in
-                   ops.fused_meter(*args, use_pallas=False)]
-    for pl_o, ref_o in zip(got_pl, got_ref):
-        np.testing.assert_allclose(pl_o, ref_o, rtol=1e-12, atol=0)
-    e, s, c, fa = got_pl
+        e, s, c = (np.asarray(o) for o in ops.fused_meter(*args))
+        ref_e, ref_s, ref_c = (np.asarray(o)
+                               for o in ref.fused_meter_ref(*args))
+        fa = np.asarray(_prefix_at(*args[5:], args[4], args[0]))
     # pass-through outputs: exact, not allclose -- the fused finalize's
     # energy segment-sum must be bit-identical to the unfused path
-    assert np.array_equal(e, w * dt)
-    assert np.array_equal(s, dt)
-    np.testing.assert_allclose(c, want_c, rtol=1e-9, atol=1e-12)
+    assert np.array_equal(e, w * dt) and np.array_equal(e, ref_e)
+    assert np.array_equal(s, dt) and np.array_equal(s, ref_s)
+    np.testing.assert_allclose(c, ref_c, rtol=CARBON_REL, atol=1e-12)
+    np.testing.assert_allclose(c, want_c, rtol=CARBON_REL, atol=1e-12)
     np.testing.assert_allclose(fa, want_fa, rtol=1e-9, atol=1e-12)
 
 
-def test_fused_meter_empty_and_zero_width():
-    from jax.experimental import enable_x64
+@pytest.mark.parametrize("max_span_s", [1e-2, 5.0, 4 * 3600.0, 86400.0,
+                                        3 * 86400.0])
+def test_fused_meter_within_derived_bound(max_span_s):
+    """Per entry, against exact rational integrals: the kernel holds the
+    derived bound ``_carbon_error_factor(kv) * F32_U`` for every span
+    regime, including starts a hair off a knot."""
+    from repro.fleet.carbon import make_trace
 
+    traces = [make_trace(s, 0.39) for s in
+              ("solar-duck", "wind-night", "flat")]
+    kt, kv, cum, per = _stacked_tables(traces)
+    rng = np.random.default_rng(int(max_span_s))
+    n = 240
+    a = rng.uniform(0.0, 2.5 * 86400.0, n)
+    a[:60] = (np.round(a[:60] / 1800.0) * 1800.0
+              + rng.uniform(-1e-3, 1e-3, 60))
+    b = a + rng.uniform(0.0, max_span_s, n)
+    g = rng.integers(0, len(traces), n).astype(np.int32)
+    with jax.enable_x64(True):
+        _, _, c = ops.fused_meter(*[jnp.asarray(x) for x in (
+            a, b, b - a, np.ones(n), g, kt, kv, cum, per)])
+    c = np.asarray(c)
+    exact = np.array([_exact_integral(traces[gi], x, y)
+                      for gi, x, y in zip(g, a, b)])
+    bound = np.array([_carbon_error_factor(traces[gi]._kv)
+                      for gi in g]) * F32_U
+    assert np.all(np.abs(c - exact) <= bound * exact)
+
+
+def test_carbon_bound_covers_every_shipped_trace():
+    """CARBON_REL is the anchor the backends are held to: it must cover
+    the derived bound of every curve the fleet can price against."""
+    from repro.fleet.carbon import TRACE_SHAPES, make_trace, trace_for_zone
+    from repro.fleet.catalog import MIXES
+    from repro.kernels.segment_trapz import CARBON_REL
+
+    curves = [make_trace(s, 0.39) for s in TRACE_SHAPES]
+    curves += [trace_for_zone(z) for z in MIXES]
+    for tr in curves:
+        assert _carbon_error_factor(tr._kv) * F32_U <= CARBON_REL, tr.name
+
+
+def test_fused_meter_empty_and_zero_width():
     from repro.fleet.carbon import solar_duck
+    from repro.fleet.mega.jaxback import _prefix_at
 
     kt, kv, cum, per = _stacked_tables([solar_duck(0.39)])
-    with enable_x64():
+    with jax.enable_x64(True):
         tabs = [jnp.asarray(x) for x in (kt, kv, cum, per)]
         empty = ops.fused_meter(jnp.zeros(0), jnp.zeros(0), jnp.zeros(0),
                                 jnp.zeros(0), jnp.zeros(0, jnp.int32),
@@ -255,20 +330,19 @@ def test_fused_meter_empty_and_zero_width():
         point = ops.fused_meter(jnp.asarray([7e4]), jnp.asarray([7e4]),
                                 jnp.asarray([0.0]), jnp.asarray([500.0]),
                                 jnp.zeros(1, jnp.int32), *tabs)
+        fa = _prefix_at(*tabs, jnp.zeros(1, jnp.int32), jnp.asarray([7e4]))
     assert all(np.asarray(o).shape == (0,) for o in empty)
-    e, s, c, fa = (np.asarray(o) for o in point)
-    assert e[0] == 0.0 and s[0] == 0.0
-    np.testing.assert_allclose(c, 0.0, atol=1e-12)
-    assert fa[0] > 0.0                      # prefix at 7e4 s into the day
+    e, s, c = (np.asarray(o) for o in point)
+    assert e[0] == 0.0 and s[0] == 0.0 and c[0] == 0.0
+    assert np.asarray(fa)[0] > 0.0          # prefix at 7e4 s into the day
 
 
 def test_fused_meter_matches_segment_trapz():
     """The fused kernel's carbon lane reproduces the standalone
-    segment_trapz kernel on a single-trace workload (same closed form,
-    stacked-table indexing vs scalar tables)."""
-    from jax.experimental import enable_x64
-
+    segment_trapz path on a single-trace workload (same integral,
+    stacked-table f32 kernel vs the scalar-table f64 reference)."""
     from repro.fleet.carbon import make_trace
+    from repro.kernels.segment_trapz import CARBON_REL
 
     trace = make_trace("wind-night", 0.39)
     kt, kv, cum, per = _stacked_tables([trace])
@@ -277,8 +351,8 @@ def test_fused_meter_matches_segment_trapz():
     a = np.sort(rng.uniform(0.0, 2.0 * trace.period_s, n))
     b = a + rng.uniform(0.0, 7200.0, n)
     w = rng.uniform(50.0, 400.0, n)
-    with enable_x64():
-        _, _, c, _ = ops.fused_meter(
+    with jax.enable_x64(True):
+        _, _, c = ops.fused_meter(
             jnp.asarray(a), jnp.asarray(b), jnp.asarray(b - a),
             jnp.asarray(w), jnp.zeros(n, jnp.int32),
             *[jnp.asarray(x) for x in (kt, kv, cum, per)])
@@ -289,4 +363,4 @@ def test_fused_meter_matches_segment_trapz():
             jnp.asarray(np.asarray(trace._cum)),
             period=trace.period_s)
     np.testing.assert_allclose(np.asarray(c), np.asarray(flat),
-                               rtol=1e-12, atol=0)
+                               rtol=CARBON_REL, atol=0)

@@ -20,8 +20,9 @@ file pins that claim the way every other layer pins its anchor
   bit-identical trace) and round-trip through the record schema --
   as does the streaming JSON-Lines form (``FleetTrace.to_jsonl``);
 * the compiled backend (``run_mega(backend="jax")``) matches the numpy
-  anchor on fleet totals to <=1e-9 relative (and bit-for-bit on
-  requests, cold starts, power timeline, and the fsum'd latency total)
+  anchor on energy to <=1e-9 relative, on carbon within the f32
+  metering kernel's derived bound (``CARBON_REL``), and bit-for-bit on
+  requests, cold starts, power timeline, and the fsum'd latency total,
   across the pinned day, generated days, and a property sweep of
   random seeds x policies x generators;
 * the big-gap cache reuses derived stream arrays across runs on the
@@ -45,6 +46,7 @@ from repro.fleet import (CarbonBreakeven, FleetTrace, MegaUnsupportedError,
                          trace_from_records)
 from repro.fleet.mega import GENERATORS
 from repro.fleet.mega.megasim import _BigGapCache, biggap_cache
+from repro.kernels.segment_trapz import CARBON_REL
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -416,21 +418,22 @@ def _jax_pair(make_scenario, **run_kw):
 
 
 def _assert_backends_match(ref, got):
-    """The backend contract: identical structural outcomes, float totals
-    to <=1e-9 relative (energy is summed in a different order on the
-    compiled path; latency totals use fsum on an identical multiset, so
-    they are exactly equal)."""
+    """The backend contract: identical structural outcomes, energy to
+    <=1e-9 relative (summed in a different order on the compiled path),
+    carbon within the metering kernel's f32 bound CARBON_REL; latency
+    totals use fsum on an identical multiset, so they are exactly
+    equal."""
     assert got.requests == ref.requests
     assert got.cold_starts == ref.cold_starts
     assert got.power_timeline == ref.power_timeline
     assert got.replica_timeline == ref.replica_timeline
     assert got.added_latency_s_total == ref.added_latency_s_total
     assert got.energy_wh == pytest.approx(ref.energy_wh, rel=REL)
-    assert got.carbon_kg == pytest.approx(ref.carbon_kg, rel=REL)
+    assert got.carbon_kg == pytest.approx(ref.carbon_kg, rel=CARBON_REL)
     assert got.parking_tax_wh == pytest.approx(ref.parking_tax_wh, rel=REL)
     for (t1, c1), (t2, c2) in zip(ref.carbon_timeline, got.carbon_timeline):
         assert t2 == t1
-        assert c2 == pytest.approx(c1, rel=REL, abs=1e-12)
+        assert c2 == pytest.approx(c1, rel=CARBON_REL, abs=1e-12)
     for rd, gd in zip(ref.devices, got.devices):
         assert gd.requests == rd.requests
         assert gd.cold_starts == rd.cold_starts
@@ -438,7 +441,7 @@ def _assert_backends_match(ref, got):
         for k in rd.energy_wh:
             assert gd.energy_wh[k] == pytest.approx(rd.energy_wh[k],
                                                     rel=REL, abs=1e-9)
-        assert gd.carbon_kg == pytest.approx(rd.carbon_kg, rel=REL,
+        assert gd.carbon_kg == pytest.approx(rd.carbon_kg, rel=CARBON_REL,
                                              abs=1e-12)
 
 
@@ -502,7 +505,7 @@ class TestJaxBackend:
         with pytest.raises(RuntimeError, match="needs jax"):
             run_mega(sc, backend="jax")
 
-    @settings(max_examples=6)
+    @settings(max_examples=6, deadline=None)
     @given(seed=st.integers(min_value=0, max_value=10_000),
            gen=st.sampled_from(sorted(GENERATORS)),
            policy=st.sampled_from([Breakeven, AlwaysOn, _ttl300]))
@@ -546,13 +549,15 @@ class TestFusedFinalize:
         for fd, ud in zip(fused.devices, unfused.devices):
             assert fd.energy_wh == ud.energy_wh
             assert fd.durations_s == ud.durations_s
-        # the carbon lane integrates the raw charge log instead of the
-        # coalesced segments: same closed form, float-assoc tolerance
-        assert fused.carbon_kg == pytest.approx(unfused.carbon_kg, rel=REL)
+        # the carbon lane integrates the raw charge log in the f32
+        # kernel instead of the coalesced segments in f64: same
+        # integral, within the kernel's derived bound
+        assert fused.carbon_kg == pytest.approx(unfused.carbon_kg,
+                                                rel=CARBON_REL)
         for (t1, c1), (t2, c2) in zip(unfused.carbon_timeline,
                                       fused.carbon_timeline):
             assert t2 == t1
-            assert c2 == pytest.approx(c1, rel=REL, abs=1e-12)
+            assert c2 == pytest.approx(c1, rel=CARBON_REL, abs=1e-12)
 
     def test_tier_billed_seconds_all_engines_agree(self, monkeypatch):
         fused, unfused = self._toggle_pair(monkeypatch)

@@ -72,7 +72,7 @@ def base6():
 
 class TestBatchedEqualsSerial:
 
-    @settings(max_examples=5)
+    @settings(max_examples=5, deadline=None)
     @given(nf=st.integers(min_value=1, max_value=3),
            nr=st.integers(min_value=1, max_value=2),
            nt=st.integers(min_value=1, max_value=2),

@@ -44,6 +44,7 @@ except ImportError:
 # pinned 3-zone fleet spec, seed, and latency bound live in conftest.py
 # (shared with test_mega / test_pricing)
 from conftest import P99_BOUND_S, PIN_SEED, ZONES3
+from repro.kernels.segment_trapz import CARBON_REL
 
 
 class TestSpecParsing:
@@ -252,17 +253,20 @@ class TestUniformZoneEquivalence:
         assert set(ref.zone_carbon_kg) == {"DEU", "IND", "USA"}
         for backend in ("numpy", "jax"):
             got = go(backend)
+            # the jax backend's carbon runs through the f32 metering
+            # kernel: held to its derived bound instead of 1e-9
+            crel = CARBON_REL if backend == "jax" else 1e-9
             assert got.energy_wh == pytest.approx(ref.energy_wh, rel=1e-9)
-            assert got.carbon_kg == pytest.approx(ref.carbon_kg, rel=1e-9)
+            assert got.carbon_kg == pytest.approx(ref.carbon_kg, rel=crel)
             for z in ref.zone_carbon_kg:
                 assert got.zone_carbon_kg[z] == pytest.approx(
-                    ref.zone_carbon_kg[z], rel=1e-9)
+                    ref.zone_carbon_kg[z], rel=crel)
                 assert got.zone_energy_wh[z] == pytest.approx(
                     ref.zone_energy_wh[z], rel=1e-9)
             for (t1, c1), (t2, c2) in zip(ref.carbon_timeline,
                                           got.carbon_timeline):
                 assert t2 == t1
-                assert c2 == pytest.approx(c1, rel=1e-9, abs=1e-12)
+                assert c2 == pytest.approx(c1, rel=crel, abs=1e-12)
 
 
 class TestZoneDecomposition:
