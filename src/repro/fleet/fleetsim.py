@@ -261,10 +261,16 @@ class FleetResult:
     gates: int = 0
     wakes: int = 0
     gated_wh_saved: float = 0.0
-    # run_mega backend instrumentation: wall-clock seconds spent in the
-    # bulk-scan phases ("biggap_s" / "billing_s" / "energy_s" /
-    # "carbon_s" and their sum "bulk_scan_s"); None for event-loop runs
+    # run_mega instrumentation (fleet/mega/spans.py, docs/SCALE.md):
+    # wall seconds per phase -- the bulk-scan phases ("biggap_s" /
+    # "billing_s" / "energy_s" / "carbon_s" and their sum
+    # "bulk_scan_s"), the run's own phases ("run_s", "scenario_s",
+    # "event_loop_s", "report_s") and the compiled calls split into
+    # "compile_s", "bulk_call_s" and "bulk_host_s" -- and the programs
+    # lowered per span ("compiles.<span>") and loaded from the
+    # persistent cache ("cache_loads"); both None for event-loop runs
     phase_timings: Optional[Dict[str, float]] = None
+    counters: Optional[Dict[str, int]] = None
     # per-zone decompositions of the global totals (one entry per zone
     # present in the fleet; single-zone runs get a one-key dict whose
     # value fsum-reduces to the global total)

@@ -61,6 +61,7 @@ import jax.numpy as jnp
 from repro.fleet.carbon import CarbonTrace
 from repro.fleet.fleetsim import DAY, FleetResult
 from repro.fleet.mega import megasim
+from repro.fleet.mega.spans import span
 from repro.fleet.mega.traces import FleetTrace, RouteTrace, _route_plan
 from repro.kernels import ops
 
@@ -259,17 +260,19 @@ def _meter_fused(keys, a, b, dt, pw, g, bucket, tdev, pseg, pk, pwp,
 class _JaxBulk:
     """Drop-in for ``megasim._NumpyBulk`` that records the bulk work
     during the event loop and retires it compiled at finalize.  See the
-    module docstring for the four phases; ``self.t`` carries the same
-    phase-timing keys the numpy backend reports, so the bench's
-    speedup rows compare like-for-like."""
+    module docstring for the four phases.  Timing: spans for the
+    finalize phases (``mega.billing``, and ``mega.meter`` or
+    ``mega.energy`` and ``mega.carbon``) and for each compiled call
+    (``mega.<program>.call``, from the ``jnp.asarray`` of its inputs to
+    its results on the host); the run claims the event loop makes
+    accumulate in ``in_loop``, as on the numpy backend."""
 
     name = "jax"
     wants_tables = True
 
     def __init__(self, n_dev: int):
         self.n_dev = n_dev
-        self.t = {"biggap_s": 0.0, "billing_s": 0.0, "energy_s": 0.0,
-                  "carbon_s": 0.0}
+        self.in_loop = {"biggap_s": 0.0, "billing_s": 0.0}
         # transition log (energy) and billing records, appended by the
         # event loop, reduced at finalize (array.array: appends like a
         # list, converts to ndarray as a buffer view instead of a
@@ -292,7 +295,6 @@ class _JaxBulk:
     # -- prepare: stacked stream matrices + nextbig tables -------------------
     def prepare(self, streams: Dict[str, "megasim._Stream"],
                 stream_Ts: Dict[str, Sequence[float]]) -> None:
-        t0 = time.perf_counter()
         mids = list(streams)
         self._sid = {mid: i for i, mid in enumerate(mids)}
         arrs = [streams[mid].arr for mid in mids]
@@ -330,15 +332,15 @@ class _JaxBulk:
                     mat[r, :arr.size] = arr
                     mat[r, arr.size:] = arr[-1]
                     Ts[r] = T
-                nb = np.asarray(_nextbig_rows(jnp.asarray(mat),
-                                              jnp.asarray(Ts)))
+                with span("mega.nextbig.call"):
+                    nb = np.asarray(_nextbig_rows(jnp.asarray(mat),
+                                                  jnp.asarray(Ts)))
                 for r, (mid, T, _arr) in enumerate(grp):
                     self._nextbig[(mid, T)] = nb[r]
                     ms = streams[mid]
                     if len(ms.biggap) >= megasim.biggap_cache.max_timeouts:
                         ms.biggap.pop(next(iter(ms.biggap)))
                     ms.biggap[("nb", T)] = nb[r]
-        self.t["biggap_s"] += time.perf_counter() - t0
 
     # -- event-loop hooks ----------------------------------------------------
     def charge(self, d: int, s: int, dt: float, p: float,
@@ -364,7 +366,7 @@ class _JaxBulk:
             else:
                 v = int(row[ms.ptr])
                 last = v if v <= ms.n - 2 else ms.n - 1
-        self.t["biggap_s"] += time.perf_counter() - t0
+        self.in_loop["biggap_s"] += time.perf_counter() - t0
         return last
 
     def absorb(self, ms, d: int, lo: int, hi: int, t_done: float) -> None:
@@ -413,29 +415,27 @@ class _JaxBulk:
                 carbon_dev, timeline = self._finalize_carbon(
                     segs, fleet_segments, trace, horizon, dev_traces)
                 tier_billed = None
-        self.t["bulk_scan_s"] = sum(self.t.values())
         return megasim._Fin(energy_j, dur_s, waits, carbon_dev, timeline,
-                            dict(self.t), tier_billed)
+                            tier_billed)
 
+    @span("mega.energy")
     def _finalize_energy(self):
-        t0 = time.perf_counter()
         n = len(self._ekey)
         m = _pow2(n)
         keys = _pad(np.asarray(self._ekey, dtype=np.int32), m, 0)
         dt = _pad(np.asarray(self._edt, dtype=np.float64), m)
         pw = _pad(np.asarray(self._epw, dtype=np.float64), m)
-        ej, ds = _energy_segsum(jnp.asarray(keys), jnp.asarray(dt),
-                                jnp.asarray(pw), num=self.n_dev * 3)
-        energy_j = np.asarray(ej).reshape(self.n_dev, 3)
-        dur_s = np.asarray(ds).reshape(self.n_dev, 3)
-        self.t["energy_s"] += time.perf_counter() - t0
+        with span("mega.energy.call"):
+            ej, ds = _energy_segsum(jnp.asarray(keys), jnp.asarray(dt),
+                                    jnp.asarray(pw), num=self.n_dev * 3)
+            energy_j = np.asarray(ej).reshape(self.n_dev, 3)
+            dur_s = np.asarray(ds).reshape(self.n_dev, 3)
         return energy_j, dur_s
 
+    @span("mega.billing")
     def _finalize_billing(self) -> np.ndarray:
-        t0 = time.perf_counter()
         scalar = np.asarray(self._scalar_waits, dtype=np.float64)
         if not self._bill:
-            self.t["billing_s"] += time.perf_counter() - t0
             return scalar
         rec = np.asarray(self._bill, dtype=np.float64)
         m = _pow2(rec.shape[0])
@@ -444,20 +444,18 @@ class _JaxBulk:
         hi = _pad(rec[:, 2].astype(np.int32), m, 0)
         tt = _pad(rec[:, 3], m)
         total = int((hi - lo).sum())
-        w = _bill_gather(jnp.asarray(self._flat), jnp.asarray(self._off),
-                         jnp.asarray(sid), jnp.asarray(lo),
-                         jnp.asarray(hi), jnp.asarray(tt),
-                         total_pad=_pow2(total))
-        waits = np.concatenate([np.asarray(w)[:total], scalar])
-        self.t["billing_s"] += time.perf_counter() - t0
-        return waits
+        with span("mega.billing.call"):
+            w = np.asarray(_bill_gather(
+                jnp.asarray(self._flat), jnp.asarray(self._off),
+                jnp.asarray(sid), jnp.asarray(lo), jnp.asarray(hi),
+                jnp.asarray(tt), total_pad=_pow2(total)))
+        return np.concatenate([w[:total], scalar])
 
+    @span("mega.carbon")
     def _finalize_carbon(self, segs, fleet_segments, trace: CarbonTrace,
                          horizon: float, dev_traces=None):
-        t0 = time.perf_counter()
         n = len(fleet_segments)
         if n == 0:
-            self.t["carbon_s"] += time.perf_counter() - t0
             return [0.0] * self.n_dev, []
         # hourly timeline, numpy-semantics bins: they cover
         # max(horizon, last segment end), the last bin absorbing any
@@ -516,38 +514,36 @@ class _JaxBulk:
                 pk[:total] = (np.arange(total) - starts[ps] + k_lo[ps])
                 pw[:total] = w_np[ps]
             m = _pow2(gn)
-            per_dev, cums = _carbon_fused(
-                jnp.asarray(_pad(a_np, m)), jnp.asarray(_pad(b_np, m)),
-                jnp.asarray(_pad(w_np, m)),          # pad weight 0
-                jnp.asarray(_pad(dev, m, 0)),
-                jnp.asarray(_pad(bucket, m, 0)),
-                jnp.asarray(pseg), jnp.asarray(pk), jnp.asarray(pw),
-                jnp.asarray(np.asarray(gtrace._kt)),
-                jnp.asarray(np.asarray(gtrace._kv)),
-                jnp.asarray(np.asarray(gtrace._cum)), jnp.asarray(tbr),
-                period=float(gtrace.period_s), n_dev=len(gdevs), nb=nb)
-            per_dev_out[gdevs] = np.asarray(per_dev)
-            cums_total += np.asarray(cums)
+            with span("mega.carbon.call"):
+                per_dev, cums = _carbon_fused(
+                    jnp.asarray(_pad(a_np, m)), jnp.asarray(_pad(b_np, m)),
+                    jnp.asarray(_pad(w_np, m)),          # pad weight 0
+                    jnp.asarray(_pad(dev, m, 0)),
+                    jnp.asarray(_pad(bucket, m, 0)),
+                    jnp.asarray(pseg), jnp.asarray(pk), jnp.asarray(pw),
+                    jnp.asarray(np.asarray(gtrace._kt)),
+                    jnp.asarray(np.asarray(gtrace._kv)),
+                    jnp.asarray(np.asarray(gtrace._cum)), jnp.asarray(tbr),
+                    period=float(gtrace.period_s), n_dev=len(gdevs), nb=nb)
+                per_dev_out[gdevs] = np.asarray(per_dev)
+                cums_total += np.asarray(cums)
         timeline = [(min((j + 1) * bin_s, end), float(cums_total[j]))
                     for j in range(nb)]
-        self.t["carbon_s"] += time.perf_counter() - t0
         return list(per_dev_out), timeline
 
+    @span("mega.meter")
     def _finalize_fused(self, trace: CarbonTrace, horizon: float,
                         dev_traces=None, tiers=None):
         """Energy, durations, carbon, timeline, and per-tier billed
         seconds from ONE ``_meter_fused`` launch over the raw charge
-        log.  Host-side prep (table stacking, bin/straddle geometry) is
-        booked under ``carbon_s`` and the compiled call under
-        ``energy_s`` so the phase-timing keys the bench and tests pin
-        keep their meaning: time spent preparing/running the carbon
-        vs energy reductions."""
-        t0 = time.perf_counter()
+        log.  ``phase_timings`` books the compiled call
+        (``mega.meter.call``) under ``energy_s`` and the rest of
+        ``mega.meter``, the host-side prep (table stacking, bin and
+        straddle geometry), under ``carbon_s``."""
         n = len(self._ekey)
         tier_names = sorted(set(tiers)) if tiers else ["on_demand"]
         if n == 0:
             z = np.zeros((self.n_dev, 3))
-            self.t["energy_s"] += time.perf_counter() - t0
             return (z, z.copy(), [0.0] * self.n_dev, [],
                     {t: 0.0 for t in tier_names})
         keys_np = np.asarray(self._ekey, dtype=np.int32)
@@ -614,28 +610,26 @@ class _JaxBulk:
                         dtype=np.int32) if tiers else \
             np.zeros(self.n_dev, dtype=np.int32)
         m = _pow2(n)
-        self.t["carbon_s"] += time.perf_counter() - t0
-        t1 = time.perf_counter()
-        ej, ds, per_dev, tier_s, cums_nb = _meter_fused(
-            jnp.asarray(_pad(keys_np, m, 0)),
-            jnp.asarray(_pad(a_np, m)), jnp.asarray(_pad(b_np, m)),
-            jnp.asarray(_pad(dt_np, m)), jnp.asarray(_pad(pw_np, m)),
-            jnp.asarray(_pad(g_np, m, 0)),
-            jnp.asarray(_pad(bucket, m, 0)), jnp.asarray(tdev),
-            jnp.asarray(pseg), jnp.asarray(pk), jnp.asarray(pwp),
-            jnp.asarray(kts), jnp.asarray(kvs), jnp.asarray(cums),
-            jnp.asarray(pers), jnp.asarray(tbr),
-            n_dev=self.n_dev, nb=nb, n_tier=len(tier_names))
-        energy_j = np.asarray(ej).reshape(self.n_dev, 3)
-        dur_s = np.asarray(ds).reshape(self.n_dev, 3)
-        cums_np = np.asarray(cums_nb)
-        timeline = [(min((j + 1) * bin_s, end), float(cums_np[j]))
-                    for j in range(nb)]
-        tier_billed = {t: float(v)
-                       for t, v in zip(tier_names, np.asarray(tier_s))}
-        self.t["energy_s"] += time.perf_counter() - t1
-        return (energy_j, dur_s, list(np.asarray(per_dev)), timeline,
-                tier_billed)
+        with span("mega.meter.call"):
+            ej, ds, per_dev, tier_s, cums_nb = _meter_fused(
+                jnp.asarray(_pad(keys_np, m, 0)),
+                jnp.asarray(_pad(a_np, m)), jnp.asarray(_pad(b_np, m)),
+                jnp.asarray(_pad(dt_np, m)), jnp.asarray(_pad(pw_np, m)),
+                jnp.asarray(_pad(g_np, m, 0)),
+                jnp.asarray(_pad(bucket, m, 0)), jnp.asarray(tdev),
+                jnp.asarray(pseg), jnp.asarray(pk), jnp.asarray(pwp),
+                jnp.asarray(kts), jnp.asarray(kvs), jnp.asarray(cums),
+                jnp.asarray(pers), jnp.asarray(tbr),
+                n_dev=self.n_dev, nb=nb, n_tier=len(tier_names))
+            energy_j = np.asarray(ej).reshape(self.n_dev, 3)
+            dur_s = np.asarray(ds).reshape(self.n_dev, 3)
+            cums_np = np.asarray(cums_nb)
+            timeline = [(min((j + 1) * bin_s, end), float(cums_np[j]))
+                        for j in range(nb)]
+            tier_billed = {t: float(v)
+                           for t, v in zip(tier_names, np.asarray(tier_s))}
+            per_dev = list(np.asarray(per_dev))
+        return energy_j, dur_s, per_dev, timeline, tier_billed
 
 
 def compiled_program_count() -> int:

@@ -64,6 +64,7 @@ from repro.fleet.catalog import (carbon_kg, energy_cost_usd,
 from repro.fleet.cluster import _make_policy
 from repro.fleet.fleetsim import (DeviceReport, FleetResult, FleetScenario,
                                   clairvoyant_bound, zone_decomposition)
+from repro.fleet.mega.spans import Recorder, recording, span
 from repro.fleet.pricing import (device_tier_map, price_fleet,
                                  tier_billed_seconds)
 from repro.fleet.router import WarmFirstRouter
@@ -251,16 +252,15 @@ class _Stream:
 class _Fin:
     """What a bulk backend hands back at finalize time."""
     __slots__ = ("energy_j", "dur_s", "waits", "carbon_dev",
-                 "carbon_timeline", "timings", "tier_billed_s")
+                 "carbon_timeline", "tier_billed_s")
 
     def __init__(self, energy_j, dur_s, waits, carbon_dev, carbon_timeline,
-                 timings, tier_billed_s=None):
+                 tier_billed_s=None):
         self.energy_j = energy_j           # [N][3] joules per state
         self.dur_s = dur_s                 # [N][3] seconds per state
         self.waits = waits                 # per-request waits, any order
         self.carbon_dev = carbon_dev       # [N] kgCO2e
         self.carbon_timeline = carbon_timeline
-        self.timings = timings             # phase -> wall seconds
         # tier -> billed seconds when the backend fused it into the
         # metering pass; None -> run_mega re-derives it from reports
         self.tier_billed_s = tier_billed_s
@@ -268,9 +268,7 @@ class _Fin:
 
 class _NumpyBulk:
     """The reference bulk backend: the exact inline numpy/Python paths
-    the simulator shipped with (the bit-exact anchor vs ``run_fleet``),
-    instrumented with per-phase wall-clock so the compiled backend's
-    bulk-scan speedup is measured like-for-like.
+    the simulator shipped with (the bit-exact anchor vs ``run_fleet``).
 
     The seam: the event loop owns all STRUCTURAL state (heap, replica
     sets, pointers) and calls the backend for every bulk operation --
@@ -279,6 +277,12 @@ class _NumpyBulk:
     see identical calls in identical order, so every control-flow
     decision (routing tie-breaks, run extents) is backend-invariant by
     construction; only the arithmetic engine differs.
+
+    Timing: finalize marks its phases as spans (``spans.py``).  The
+    calls the event loop makes per event cannot carry a span, so their
+    wall accumulates in ``in_loop`` under the ``phase_timings`` key it
+    belongs to: run claims under ``biggap_s``, waiter billing under
+    ``billing_s``.
     """
 
     name = "numpy"
@@ -288,8 +292,7 @@ class _NumpyBulk:
         self.energy_j = [[0.0, 0.0, 0.0] for _ in range(n_dev)]
         self.dur_s = [[0.0, 0.0, 0.0] for _ in range(n_dev)]
         self.waits: List[float] = []
-        self.t = {"biggap_s": 0.0, "billing_s": 0.0, "energy_s": 0.0,
-                  "carbon_s": 0.0}
+        self.in_loop = {"biggap_s": 0.0, "billing_s": 0.0}
 
     def prepare(self, streams, stream_Ts) -> None:
         pass
@@ -306,14 +309,14 @@ class _NumpyBulk:
         big = ms.biggaps(T)
         j = int(np.searchsorted(big, ms.ptr))
         last = int(big[j]) if j < big.size else ms.n - 1
-        self.t["biggap_s"] += time.perf_counter() - t0
+        self.in_loop["biggap_s"] += time.perf_counter() - t0
         return last
 
     def absorb(self, ms: _Stream, d: int, lo: int, hi: int,
                t_done: float) -> None:
         t0 = time.perf_counter()
         ms.waiters.setdefault(d, []).extend(ms.arr[lo:hi].tolist())
-        self.t["billing_s"] += time.perf_counter() - t0
+        self.in_loop["billing_s"] += time.perf_counter() - t0
 
     def wait_one(self, ms: _Stream, d: int, t: float) -> None:
         ms.waiters.setdefault(d, []).append(t)
@@ -327,33 +330,64 @@ class _NumpyBulk:
             return 0
         t0 = time.perf_counter()
         self.waits.extend(t - a for a in w)
-        self.t["billing_s"] += time.perf_counter() - t0
+        self.in_loop["billing_s"] += time.perf_counter() - t0
         return len(w)
 
     def finalize(self, segs, fleet_segments, trace, horizon: float,
                  dev_traces=None, tiers=None) -> _Fin:
-        t0 = time.perf_counter()
-        waits = np.asarray(self.waits, dtype=np.float64)
-        self.t["billing_s"] += time.perf_counter() - t0
-        t0 = time.perf_counter()
-        if dev_traces is not None and any(tr is not trace
-                                          for tr in dev_traces):
-            # multi-zone fleet: each device integrates against its own
-            # zone's trace; the fleet timeline folds the per-device
-            # segments in the exact order fleet_segments concatenates
-            carbon_dev = [tr.carbon_for_segments(s)
-                          for tr, s in zip(dev_traces, segs)]
-            timeline = carbon_timeline_multi_kg(
-                [(tr, sg) for tr, s in zip(dev_traces, segs) for sg in s],
-                end_s=horizon)
-        else:
-            carbon_dev = [trace.carbon_for_segments(s) for s in segs]
-            timeline = carbon_timeline_kg(trace, fleet_segments,
-                                          end_s=horizon)
-        self.t["carbon_s"] += time.perf_counter() - t0
-        self.t["bulk_scan_s"] = sum(self.t.values())
-        return _Fin(self.energy_j, self.dur_s, waits, carbon_dev, timeline,
-                    dict(self.t))
+        with span("mega.billing"):
+            waits = np.asarray(self.waits, dtype=np.float64)
+        with span("mega.carbon"):
+            if dev_traces is not None and any(tr is not trace
+                                              for tr in dev_traces):
+                # multi-zone fleet: each device integrates against its
+                # own zone's trace; the fleet timeline folds the
+                # per-device segments in the exact order fleet_segments
+                # concatenates
+                carbon_dev = [tr.carbon_for_segments(s)
+                              for tr, s in zip(dev_traces, segs)]
+                timeline = carbon_timeline_multi_kg(
+                    [(tr, sg) for tr, s in zip(dev_traces, segs)
+                     for sg in s], end_s=horizon)
+            else:
+                carbon_dev = [trace.carbon_for_segments(s) for s in segs]
+                timeline = carbon_timeline_kg(trace, fleet_segments,
+                                              end_s=horizon)
+        return _Fin(self.energy_j, self.dur_s, waits, carbon_dev, timeline)
+
+
+def _phase_timings(rec: Recorder, in_loop: Dict[str, float]
+                   ) -> Dict[str, float]:
+    """``FleetResult.phase_timings`` from one run's spans.
+
+    The five bulk keys keep their meaning on both backends: big-gap
+    tables and run claims (``biggap_s``), waiter billing
+    (``billing_s``), the energy reduction (``energy_s``; on the fused
+    path the compiled metering call), the carbon integral
+    (``carbon_s``; on the fused path the metering pass's host side),
+    and their sum ``bulk_scan_s``.  The run claims and numpy's waiter
+    billing happen inside the event loop, so ``bulk_scan_s`` overlaps
+    ``event_loop_s`` by them.  The rest is wall per phase, with each
+    compiled call (``mega.*.call``) split into ``compile_s`` and
+    ``bulk_call_s`` and the bulk phases' host side in ``bulk_host_s``;
+    ``mega.prepare`` + ``mega.finalize`` is ``bulk_host_s`` +
+    ``bulk_call_s`` + the compiles inside the calls, which are all of
+    ``compile_s``."""
+    w = rec.wall
+    calls = [s for s in rec.spans if s.name.endswith(".call")]
+    call_s = sum((s.wall for s in calls), 0.0)
+    t = {"biggap_s": in_loop["biggap_s"] + w("mega.prepare"),
+         "billing_s": in_loop["billing_s"] + w("mega.billing"),
+         "energy_s": w("mega.energy") + w("mega.meter.call"),
+         "carbon_s": (w("mega.carbon") + w("mega.meter")
+                      - w("mega.meter.call"))}
+    t["bulk_scan_s"] = sum(t.values())
+    t.update(run_s=w("mega.run"), scenario_s=w("mega.scenario"),
+             event_loop_s=w("mega.event_loop"), report_s=w("mega.report"),
+             compile_s=rec.compile_s,
+             bulk_call_s=call_s - sum((s.compile_s for s in calls), 0.0),
+             bulk_host_s=w("mega.prepare") + w("mega.finalize") - call_s)
+    return t
 
 
 def run_mega(scenario: FleetScenario, *,
@@ -374,9 +408,21 @@ def run_mega(scenario: FleetScenario, *,
     backends drive the identical structural event loop, so request
     counts and cold starts are equal and float totals agree to <=1e-9
     relative (pinned in tests).  ``FleetResult.phase_timings`` reports
-    per-phase wall seconds for either backend.
+    wall seconds per phase and ``FleetResult.counters`` the programs
+    lowered and loaded, for either backend (``spans.py``,
+    docs/SCALE.md).
     """
-    sc = scenario
+    with recording("mega.run") as rec:
+        res, in_loop = _run_mega(scenario, compute_bound, backend, rec)
+    res.phase_timings = _phase_timings(rec, in_loop)
+    res.counters = dict(rec.counters)
+    return res
+
+
+def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
+              rec: Recorder) -> Tuple[FleetResult, Dict[str, float]]:
+    """The body of ``run_mega``, marking its phases on ``rec``; returns
+    the result and the backend's in-loop timings."""
     if backend == "numpy":
         _Bulk = _NumpyBulk
     elif backend == "jax":
@@ -392,6 +438,7 @@ def run_mega(scenario: FleetScenario, *,
         raise ValueError(
             f"unknown backend {backend!r}: expected 'numpy' or 'jax'")
     # ---- scope guard ------------------------------------------------------
+    rec.phase("mega.scenario")
     if not (sc.router == "warm-first"
             or isinstance(sc.router, WarmFirstRouter)):
         raise MegaUnsupportedError(
@@ -547,7 +594,6 @@ def run_mega(scenario: FleetScenario, *,
                 if T not in Ts:
                     Ts.append(T)
             stream_Ts[mid] = Ts
-        bulk.prepare(streams, stream_Ts)
 
     reps: Dict[Tuple[int, str], _Rep] = {}
 
@@ -833,6 +879,10 @@ def run_mega(scenario: FleetScenario, *,
     for fm in sc.models:        # timeline origin, including zero-replica
         ms = streams[fm.spec.model_id]
         replica_log[ms.mid].append((0.0, len(ms.res)))
+    if bulk.wants_tables:
+        rec.phase("mega.prepare")
+        bulk.prepare(streams, stream_Ts)
+    rec.phase("mega.event_loop")
     for fm in sc.models:        # kick every stream
         ms = streams[fm.spec.model_id]
         if ms.n == 0:
@@ -894,6 +944,7 @@ def run_mega(scenario: FleetScenario, *,
                 f"{ms.n - ms.ptr} arrivals unserved")
     for d in range(N):
         _trans(d, final_clock, state[d], watts[d])   # totals() flush
+    rec.phase(None)
 
     # ---- bulk finalize: billing, energy buckets, carbon integration ------
     fleet_segments: List[Tuple[float, float, float]] = []
@@ -901,6 +952,7 @@ def run_mega(scenario: FleetScenario, *,
         fleet_segments.extend(segs[d])
     dev_trace_list = [dev_traces_by_id[did] for did in dids]
     tiers_map = device_tier_map(sc.devices, sc.price_tier)
+    rec.phase("mega.finalize")
     fin = bulk.finalize(segs, fleet_segments, trace, horizon,
                         dev_trace_list,
                         tiers=[tiers_map[did] for did in dids])
@@ -908,6 +960,7 @@ def run_mega(scenario: FleetScenario, *,
     dur_s = fin.dur_s
 
     # ---- reports (same construction as run_fleet) -------------------------
+    rec.phase("mega.report")
     reports = []
     for d in range(N):
         e_wh = {_STATE_KEYS[s]: energy_j[d][s] / 3600.0
@@ -976,9 +1029,8 @@ def run_mega(scenario: FleetScenario, *,
         replica_timeline={mid: list(log)
                           for mid, log in replica_log.items()},
         state_energy_wh=state_wh, state_durations_s=state_s,
-        phase_timings=fin.timings,
         cost_usd=cost.cost_usd, gpu_hours_usd=cost.gpu_hours_usd,
         device_gpu_usd=cost.device_gpu_usd,
         device_cost_usd=cost.device_cost_usd,
         zone_cost_usd=cost.zone_cost_usd, device_tiers=cost.device_tiers,
-        tier_billed_s=tier_billed)
+        tier_billed_s=tier_billed), bulk.in_loop

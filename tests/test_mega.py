@@ -480,7 +480,8 @@ class TestJaxBackend:
         sc = mixed_fleet_scenario(Breakeven, "warm-first", seed=PIN_SEED)
         res = run_mega(sc, backend="jax")
         keys = {"biggap_s", "billing_s", "energy_s", "carbon_s",
-                "bulk_scan_s"}
+                "bulk_scan_s", "run_s", "scenario_s", "event_loop_s",
+                "report_s", "compile_s", "bulk_call_s", "bulk_host_s"}
         assert set(res.phase_timings) == keys
         assert all(v >= 0.0 for v in res.phase_timings.values())
 
@@ -585,9 +586,10 @@ class TestFusedFinalize:
 
     def test_phase_timing_keys_unchanged(self):
         res = run_mega(self._scenario(), backend="jax")
-        assert set(res.phase_timings) == {"biggap_s", "billing_s",
-                                          "energy_s", "carbon_s",
-                                          "bulk_scan_s"}
+        assert set(res.phase_timings) == {
+            "biggap_s", "billing_s", "energy_s", "carbon_s", "bulk_scan_s",
+            "run_s", "scenario_s", "event_loop_s", "report_s", "compile_s",
+            "bulk_call_s", "bulk_host_s"}
 
 
 class TestMegaSweep:

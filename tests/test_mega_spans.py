@@ -1,0 +1,269 @@
+"""Phase spans and the compile counter of the mega simulator
+(``fleet/mega/spans.py``).
+
+* the recorder nests spans, links parents, and closes what is left
+  open, phase by phase and on an error;
+* recorders of concurrent threads never mix;
+* ``phase_timings`` splits the bulk phases by construction:
+  ``mega.prepare`` + ``mega.finalize`` = ``bulk_host_s`` +
+  ``bulk_call_s`` + ``compile_s``, and ``bulk_scan_s`` exceeds that by
+  the run claims made inside the event loop;
+* each lowering is counted under the span it happened in, as many as
+  the compiled backend's jit caches grew by; a persistent-cache load is
+  counted as ``cache_loads``;
+* every span reaches the profiler's trace, and the numpy backend
+  records its spans with ``jax`` not importable.
+"""
+import contextlib
+import os
+import subprocess
+import sys
+import textwrap
+import threading
+
+import numpy as np
+import pytest
+
+from repro.core.scheduler import Breakeven
+from repro.fleet import flash_crowd, mixed_fleet_scenario, run_mega
+from repro.fleet.mega import megasim, spans
+
+from conftest import PIN_SEED
+
+PHASES = ("mega.scenario", "mega.event_loop", "mega.finalize",
+          "mega.report")
+
+
+def _recorded(monkeypatch, sc, backend):
+    """``run_mega``'s result and the recorder it filled."""
+    got = {}
+    real = megasim.recording
+
+    @contextlib.contextmanager
+    def keep(root):
+        with real(root) as rec:
+            got["rec"] = rec
+            yield rec
+
+    monkeypatch.setattr(megasim, "recording", keep)
+    res = run_mega(sc, backend=backend, compute_bound=False)
+    return res, got["rec"]
+
+
+def _self_s(rec, i):
+    return rec.spans[i].wall - sum(s.wall for s in rec.spans
+                                   if s.parent == i)
+
+
+def test_nesting_parents_and_self_time():
+    with spans.recording("root") as rec:
+        rec.phase("a")
+        with spans.span("a.call"):
+            pass
+        rec.phase("b")
+        with spans.span("b.inner"):
+            rec.open("left-open")          # closed with its phase
+        rec.phase(None)
+        with spans.span("tail"):
+            pass
+    names = [s.name for s in rec.spans]
+    assert names == ["root", "a", "a.call", "b", "b.inner", "left-open",
+                     "tail"]
+    parent = {s.name: (names[s.parent] if s.parent >= 0 else None)
+              for s in rec.spans}
+    assert parent == {"root": None, "a": "root", "a.call": "a",
+                      "b": "root", "b.inner": "b", "left-open": "b.inner",
+                      "tail": "root"}
+    for i, s in enumerate(rec.spans):
+        assert s.end >= s.start
+        assert _self_s(rec, i) >= 0.0
+        if s.parent >= 0:
+            p = rec.spans[s.parent]
+            assert p.start <= s.start and s.end <= p.end
+    assert rec.wall("root") == pytest.approx(
+        _self_s(rec, 0) + rec.wall("a") + rec.wall("b") + rec.wall("tail"))
+    assert not rec._open
+
+
+def test_spans_close_on_error_and_do_nothing_without_a_recorder():
+    with spans.span("orphan"):              # no recorder: a no-op
+        pass
+    with pytest.raises(KeyError):
+        with spans.recording("root") as rec:
+            rec.phase("p")
+            with spans.span("p.call"):
+                raise KeyError("boom")
+    assert [s.name for s in rec.spans] == ["root", "p", "p.call"]
+    assert not rec._open
+    assert spans._current.get() is None
+
+
+def test_recorders_of_threads_do_not_mix():
+    recs, errors = {}, []
+    barrier = threading.Barrier(8)
+
+    def work(k):
+        try:
+            with spans.recording(f"root{k}") as rec:
+                barrier.wait(timeout=10)
+                for j in range(50):
+                    with spans.span(f"t{k}.{j}"):
+                        pass
+            recs[k] = rec
+        except Exception as exc:           # pragma: no cover - reported
+            errors.append(exc)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        threads = [threading.Thread(target=work, args=(k,))
+                   for k in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    assert not errors and len(recs) == 8
+    for k, rec in recs.items():
+        assert [s.name for s in rec.spans] == \
+            [f"root{k}"] + [f"t{k}.{j}" for j in range(50)]
+        assert all(s.parent == 0 for s in rec.spans[1:])
+
+
+@pytest.mark.parametrize("fused", [True, False], ids=["fused", "unfused"])
+def test_bulk_split_is_exact_by_construction(monkeypatch, fused):
+    from repro.fleet.mega import jaxback
+    monkeypatch.setattr(jaxback, "FUSED", fused)
+    sc = mixed_fleet_scenario(Breakeven, "warm-first", seed=PIN_SEED)
+    res, rec = _recorded(monkeypatch, sc, "jax")
+    pt = res.phase_timings
+    calls = [s for s in rec.spans if s.name.endswith(".call")]
+    assert {s.name for s in calls} >= (
+        {"mega.nextbig.call", "mega.billing.call", "mega.meter.call"}
+        if fused else {"mega.nextbig.call", "mega.billing.call",
+                       "mega.energy.call", "mega.carbon.call"})
+    # every compile of the run happened inside a compiled call
+    assert sum(s.compile_s for s in calls) == pytest.approx(
+        pt["compile_s"], rel=1e-12, abs=1e-12)
+    bulk = pt["bulk_host_s"] + pt["bulk_call_s"] + pt["compile_s"]
+    assert rec.wall("mega.prepare") + rec.wall("mega.finalize") == \
+        pytest.approx(bulk, rel=1e-9, abs=1e-9)
+    # bulk_scan_s also holds the run claims made inside the event loop
+    assert pt["bulk_scan_s"] - bulk >= -1e-9
+    assert pt["bulk_scan_s"] == pytest.approx(
+        pt["biggap_s"] + pt["billing_s"] + pt["energy_s"]
+        + pt["carbon_s"], rel=1e-12)
+    assert pt["event_loop_s"] < pt["run_s"]
+    assert pt["scenario_s"] + pt["event_loop_s"] + pt["report_s"] \
+        + rec.wall("mega.prepare") + rec.wall("mega.finalize") \
+        <= pt["run_s"] + 1e-9
+    assert all(v >= 0.0 for v in pt.values())
+    root = [s.name for s in rec.spans if s.parent == 0]
+    assert root == ["mega.scenario", "mega.prepare", "mega.event_loop",
+                    "mega.finalize", "mega.report"]
+
+
+def test_compiles_counted_where_they_happen():
+    from repro.fleet.mega import jaxback
+    # a day size no other test runs: its billing gather lowers afresh
+    tr = flash_crowd(n_routes=5, fleet="h100+a100+l40s", seed=4242,
+                     horizon_s=5 * 3600.0)
+    before = jaxback.compiled_program_count()
+    res = run_mega(tr.to_scenario(Breakeven), backend="jax",
+                   compute_bound=False)
+    grown = jaxback.compiled_program_count() - before
+    lowered = {k: v for k, v in res.counters.items()
+               if k.startswith("compiles.")}
+    assert lowered.get("compiles.mega.billing.call", 0) >= 1
+    assert sum(lowered.values()) == grown
+    assert all(k.endswith(".call") for k in lowered)
+    assert res.phase_timings["compile_s"] > 0.0
+    # the same day again lowers nothing
+    again = run_mega(tr.to_scenario(Breakeven), backend="jax",
+                     compute_bound=False)
+    assert not any(k.startswith("compiles.") for k in again.counters)
+    assert again.phase_timings["compile_s"] == 0.0
+
+
+def test_compiles_outside_a_recorder_are_not_counted():
+    import jax
+    with spans.recording("root") as rec:
+        with spans.span("inside"):
+            jax.jit(lambda x: x * 3.0 + 1.0)(np.ones(7, np.float32))
+    jax.jit(lambda x: x * 5.0 - 2.0)(np.ones(9, np.float32))
+    assert rec.counters.get("compiles.inside") == 1
+    assert sum(v for k, v in rec.counters.items()
+               if k.startswith("compiles.")) == 1
+    assert rec.compile_s > 0.0
+    assert rec.spans[1].compile_s == rec.compile_s
+
+
+def test_cache_load_counted(tmp_path):
+    script = textwrap.dedent(f"""
+        import jax, jax.numpy as jnp
+        jax.config.update("jax_compilation_cache_dir", {str(tmp_path)!r})
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+        from repro.fleet.mega import spans
+        f = jax.jit(lambda x: jnp.cumsum(x) * 2.0)
+        x = jnp.ones(11)
+        counts = []
+        for _ in range(2):
+            jax.clear_caches()
+            with spans.recording("root") as rec:
+                with spans.span("step"):
+                    f(x).block_until_ready()
+            counts.append((rec.counters.get("compiles.step", 0),
+                           rec.counters.get("cache_loads", 0)))
+        print(counts)
+    """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "src")]
+        + [p for p in [env.get("PYTHONPATH")] if p])
+    out = subprocess.run([sys.executable, "-c", script], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr[-2000:]
+    # a fresh compile, then the same program loaded from the cache:
+    # both lower once
+    assert out.stdout.strip().splitlines()[-1] == "[(1, 0), (1, 1)]"
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    sc = mixed_fleet_scenario(Breakeven, "warm-first", seed=PIN_SEED,
+                              horizon_s=6 * 3600.0)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        run_mega(sc, backend="jax", compute_bound=False)
+    finally:
+        jax.profiler.stop_trace()
+    found = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+             for f in fs if f.endswith(".xplane.pb")]
+    assert found
+    names = {e.name for p in ProfileData.from_file(found[0]).planes
+             if p.name.startswith("/host:")
+             for ln in p.lines for e in ln.events}
+    assert {"mega.run", "mega.prepare", "mega.nextbig.call",
+            "mega.meter.call", *PHASES} <= names
+
+
+def test_numpy_backend_records_spans_without_jax(monkeypatch):
+    monkeypatch.setitem(sys.modules, "jax", None)    # import -> error
+    sc = mixed_fleet_scenario(Breakeven, "warm-first", seed=PIN_SEED,
+                              horizon_s=6 * 3600.0)
+    res, rec = _recorded(monkeypatch, sc, "numpy")
+    names = [s.name for s in rec.spans]
+    assert names[0] == "mega.run"
+    assert [s.name for s in rec.spans if s.parent == 0] == list(PHASES)
+    assert "mega.prepare" not in names
+    assert not any(n.endswith(".call") for n in names)
+    pt = res.phase_timings
+    assert res.counters == {}
+    assert pt["compile_s"] == 0.0 and pt["bulk_call_s"] == 0.0
+    assert pt["bulk_host_s"] == pytest.approx(rec.wall("mega.finalize"))
+    assert pt["bulk_scan_s"] >= pt["carbon_s"] > 0.0
