@@ -18,7 +18,8 @@ programs behind the ``_NumpyBulk`` seam:
     replicas are recorded as (stream, lo, hi, drain-time) references,
     never materialized per element; ``finalize`` expands every record
     in one ragged gather (``searchsorted`` over the record-start
-    prefix sums, indexed into the stacked stream arrays) and the wait
+    prefix sums, indexed into the stacked stream arrays, which are
+    padded to their power-of-two bucket like the records) and the wait
     of each request is one vectorized subtract.
   * **energy accounting** -- each power-state transition appends
     ``(device*3 + state, dt, watts)``; per-(device, state) joules and
@@ -35,8 +36,9 @@ convention) via the ``jax.enable_x64`` scope, which is thread-local and
 does not disturb the f32 kernel tests elsewhere in the repo.  The
 kernel itself runs in f32 (Mosaic has no 64-bit types) on the in-period
 part of each carbon integral only.  All array programs pad to
-power-of-two sizes with masked/zero-weight tails, so a sweep over many
-same-shaped days reuses every compiled program.
+power-of-two sizes with masked/zero-weight tails, so days of different
+sizes whose arrivals, records and log fall in the same buckets reuse
+every compiled program.
 
 Both backends drive the identical event loop and see identical calls,
 so requests/cold starts are equal, energy and dollars agree to <=1e-9
@@ -119,6 +121,10 @@ def _bill_gather(flat: jnp.ndarray, off: jnp.ndarray, sid: jnp.ndarray,
     (``searchsorted`` side='right' also steps over zero-length pad
     records), and its arrival index is the offset within that record.
     Slots past the real total hit pad records; callers slice them off.
+    ``flat`` is the stacked arrivals padded to their power-of-two
+    bucket, so the program lowers once per bucket of arrivals, records
+    and total, not once per day size; the clip keeps pad slots inside
+    it.
     """
     cnt = hi - lo
     starts = jnp.cumsum(cnt) - cnt
@@ -302,8 +308,12 @@ class _JaxBulk:
         off = np.zeros(len(arrs) + 1, dtype=np.int64)
         np.cumsum(lens, out=off[1:])
         self._off = off[:-1].astype(np.int32)
-        self._flat = (np.concatenate(arrs) if arrs
-                      else np.empty(0, dtype=np.float64))
+        # padded to its power-of-two bucket like every other compiled
+        # input, so a day of a new size reuses the billing gather's
+        # program; real slots never index the pad
+        self._flat = _pad(np.concatenate(arrs) if arrs
+                          else np.empty(0, dtype=np.float64),
+                          _pow2(off[-1]))
         # one nextbig row per (stream, candidate timeout), bucketed by
         # padded length so each bucket is a single static-shape compile;
         # computed rows are parked in the stream's shared biggap dict
@@ -792,7 +802,8 @@ def run_mega_sweep(scenarios=None, *, seeds: Optional[Sequence[int]] = None,
     every compiled bulk program -- nextbig scans, billing gather,
     energy segment-sums, carbon integrals -- is shared across points
     through the power-of-two shape buckets, so the batch pays each
-    compile once: point 1 is compile-bound, points 2..P run hot.
+    compile once per bucket (and per count of hourly bins), not per
+    point: point 1 is compile-bound, points 2..P run hot.
     Returns one ``FleetResult`` per point, in input order.
 
     ``on_unsupported="skip"`` returns ``None`` for points outside

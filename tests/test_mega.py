@@ -455,6 +455,25 @@ class TestJaxBackend:
         assert np.array_equal(np.asarray(ref.latencies_s),
                               np.asarray(got.latencies_s))
 
+    @pytest.mark.parametrize("n", [512, 513], ids=["pow2", "pow2+1"])
+    def test_gather_exact_at_the_arrival_pad_boundary(self, n):
+        # the billing gather reads the arrivals padded to their
+        # power-of-two bucket: no pad at 512, 511 pad slots at 513
+        tr = flash_crowd(n_routes=4, fleet="h100+a100+l40s", seed=4242,
+                         horizon_s=12 * 3600.0)
+        cut = np.sort(np.concatenate([r.arrivals_s
+                                      for r in tr.routes]))[n - 1]
+        day = dataclasses.replace(tr, routes=tuple(
+            dataclasses.replace(r, arrivals_s=r.arrivals_s[
+                r.arrivals_s <= cut]) for r in tr.routes))
+        assert day.requests == n
+        ref, got = _jax_pair(lambda: day.to_scenario(Breakeven),
+                             compute_bound=False)
+        _assert_backends_match(ref, got)
+        assert np.count_nonzero(ref.latencies_s) > 0
+        assert np.array_equal(np.asarray(ref.latencies_s),
+                              np.asarray(got.latencies_s))
+
     @pytest.mark.parametrize("gen", [flash_crowd, product_launch,
                                      regional_outage],
                              ids=["flash-crowd", "product-launch",

@@ -10,7 +10,8 @@
   the run claims made inside the event loop;
 * each lowering is counted under the span it happened in, as many as
   the compiled backend's jit caches grew by; a persistent-cache load is
-  counted as ``cache_loads``;
+  counted as ``cache_loads``; a day of a new size whose inputs fall in
+  the buckets of one already run lowers nothing;
 * every span reaches the profiler's trace, and the numpy backend
   records its spans with ``jax`` not importable.
 """
@@ -28,7 +29,7 @@ from repro.core.scheduler import Breakeven
 from repro.fleet import flash_crowd, mixed_fleet_scenario, run_mega
 from repro.fleet.mega import megasim, spans
 
-from conftest import PIN_SEED
+from conftest import PIN_SEED, REL
 
 PHASES = ("mega.scenario", "mega.event_loop", "mega.finalize",
           "mega.report")
@@ -167,9 +168,11 @@ def test_bulk_split_is_exact_by_construction(monkeypatch, fused):
 
 def test_compiles_counted_where_they_happen():
     from repro.fleet.mega import jaxback
-    # a day size no other test runs: its billing gather lowers afresh
+    # other tests may have run this day's buckets: drop the billing
+    # gather's programs so it lowers afresh
     tr = flash_crowd(n_routes=5, fleet="h100+a100+l40s", seed=4242,
                      horizon_s=5 * 3600.0)
+    jaxback._bill_gather.clear_cache()
     before = jaxback.compiled_program_count()
     res = run_mega(tr.to_scenario(Breakeven), backend="jax",
                    compute_bound=False)
@@ -185,6 +188,28 @@ def test_compiles_counted_where_they_happen():
                      compute_bound=False)
     assert not any(k.startswith("compiles.") for k in again.counters)
     assert again.phase_timings["compile_s"] == 0.0
+
+
+def test_a_new_day_size_in_the_same_buckets_compiles_nothing():
+    from test_mega import _assert_backends_match
+    # 735 and 658 arrivals: one 1024-entry bucket, and their billing
+    # records, waits, charge logs, streams and hourly bins share theirs
+    days = [flash_crowd(n_routes=4, fleet="h100+a100+l40s", seed=seed,
+                        horizon_s=12 * 3600.0) for seed in (4242, 4243)]
+    assert days[0].requests != days[1].requests
+    run_mega(days[0].to_scenario(Breakeven), backend="jax",
+             compute_bound=False)
+    got = run_mega(days[1].to_scenario(Breakeven), backend="jax",
+                   compute_bound=False)
+    assert "compiles.mega.billing.call" not in got.counters
+    assert not any(k.startswith("compiles.") for k in got.counters)
+    assert got.phase_timings["compile_s"] == 0.0
+    ref = run_mega(days[1].to_scenario(Breakeven), backend="numpy",
+                   compute_bound=False)
+    _assert_backends_match(ref, got)
+    assert np.array_equal(np.asarray(ref.latencies_s),
+                          np.asarray(got.latencies_s))
+    assert got.cost_usd == pytest.approx(ref.cost_usd, rel=REL)
 
 
 def test_compiles_outside_a_recorder_are_not_counted():

@@ -85,6 +85,7 @@ def test_meter_fused_compiles_with_the_kernel(chip, monkeypatch):
 def test_bill_gather_compiles(chip):
     f64, i32 = jnp.float64, jnp.int32
     recs = (1 << 17,)                   # billing records (107,209 real)
+    # the arrivals (1,032,473 real), padded to 2^20 like the log
     args = [((N_ENTRIES,), f64), ((N_DEV,), i32),
             (recs, i32), (recs, i32), (recs, i32), (recs, f64)]
     _, compiled = _compile(jaxback._bill_gather.__wrapped__, chip, args,
