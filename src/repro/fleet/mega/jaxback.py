@@ -22,7 +22,8 @@ programs behind the ``_NumpyBulk`` seam:
     padded to their power-of-two bucket like the records) and the wait
     of each request is one vectorized subtract.
   * **energy accounting** -- each power-state transition appends
-    ``(device*3 + state, dt, watts)``; per-(device, state) joules and
+    ``(device*S + state, dt, watts)`` (``S`` power states: 3, or 4
+    when requests take service time); per-(device, state) joules and
     seconds are two ``jax.ops.segment_sum`` calls at finalize.
   * **carbon integration** -- the power-timeline x ``CarbonTrace``
     trapezoid integral runs through the ``kernels/segment_trapz``
@@ -140,7 +141,7 @@ def _bill_gather(flat: jnp.ndarray, off: jnp.ndarray, sid: jnp.ndarray,
 def _energy_segsum(keys: jnp.ndarray, dt: jnp.ndarray, pw: jnp.ndarray, *,
                    num: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Per-(device, state) joules and seconds from the transition log
-    (keys = device*3 + state; pad rows carry dt = 0)."""
+    (keys = device*S + state; pad rows carry dt = 0)."""
     return (jax.ops.segment_sum(dt * pw, keys, num_segments=num),
             jax.ops.segment_sum(dt, keys, num_segments=num))
 
@@ -222,10 +223,11 @@ def _prefix_at(kt: jnp.ndarray, kv: jnp.ndarray, cum: jnp.ndarray,
             + dt * (kv_j + v_p) * 0.5)
 
 
-@functools.partial(jax.jit, static_argnames=("n_dev", "nb", "n_tier"))
+@functools.partial(jax.jit,
+                   static_argnames=("n_dev", "nb", "n_tier", "n_state"))
 def _meter_fused(keys, a, b, dt, pw, g, bucket, tdev, pseg, pk, pwp,
                  kts, kvs, cums, pers, tbr, *,
-                 n_dev: int, nb: int, n_tier: int):
+                 n_dev: int, nb: int, n_tier: int, n_state: int = 3):
     """The whole metering reduction in one compiled program fed by ONE
     metering pass (``ops.fused_meter``) over the raw charge log:
 
@@ -244,9 +246,9 @@ def _meter_fused(keys, a, b, dt, pw, g, bucket, tdev, pseg, pk, pwp,
         powered-on, so raw seconds == billed seconds).
     """
     e, s, c = ops.fused_meter(a, b, dt, pw, g, kts, kvs, cums, pers)
-    ej = jax.ops.segment_sum(e, keys, num_segments=n_dev * 3)
-    ds = jax.ops.segment_sum(s, keys, num_segments=n_dev * 3)
-    dev = keys // 3
+    ej = jax.ops.segment_sum(e, keys, num_segments=n_dev * n_state)
+    ds = jax.ops.segment_sum(s, keys, num_segments=n_dev * n_state)
+    dev = keys // n_state
     per_dev = jax.ops.segment_sum(c, dev, num_segments=n_dev) / _J_PER_KWH
     tier_s = jax.ops.segment_sum(s, tdev[dev], num_segments=n_tier)
     full = jnp.cumsum(jax.ops.segment_sum(c, bucket, num_segments=nb))
@@ -276,8 +278,9 @@ class _JaxBulk:
     name = "jax"
     wants_tables = True
 
-    def __init__(self, n_dev: int):
+    def __init__(self, n_dev: int, n_state: int):
         self.n_dev = n_dev
+        self.n_state = n_state      # power-state codes a device can hold
         self.in_loop = {"biggap_s": 0.0, "billing_s": 0.0}
         # transition log (energy) and billing records, appended by the
         # event loop, reduced at finalize (array.array: appends like a
@@ -355,7 +358,7 @@ class _JaxBulk:
     # -- event-loop hooks ----------------------------------------------------
     def charge(self, d: int, s: int, dt: float, p: float,
                a: float = 0.0, b: float = 0.0) -> None:
-        self._ekey.append(d * 3 + s)
+        self._ekey.append(d * self.n_state + s)
         self._edt.append(dt)
         self._epw.append(p)
         self._ea.append(a)
@@ -392,6 +395,9 @@ class _JaxBulk:
             ent = ms.waiters[d] = [0, []]
         ent[0] += 1
         ent[1].append(t)
+
+    def wait(self, w: float) -> None:
+        self._scalar_waits.append(w)
 
     def waiter_count(self, ms, d: int) -> int:
         ent = ms.waiters.get(d)
@@ -437,9 +443,10 @@ class _JaxBulk:
         pw = _pad(np.asarray(self._epw, dtype=np.float64), m)
         with span("mega.energy.call"):
             ej, ds = _energy_segsum(jnp.asarray(keys), jnp.asarray(dt),
-                                    jnp.asarray(pw), num=self.n_dev * 3)
-            energy_j = np.asarray(ej).reshape(self.n_dev, 3)
-            dur_s = np.asarray(ds).reshape(self.n_dev, 3)
+                                    jnp.asarray(pw),
+                                    num=self.n_dev * self.n_state)
+            energy_j = np.asarray(ej).reshape(self.n_dev, self.n_state)
+            dur_s = np.asarray(ds).reshape(self.n_dev, self.n_state)
         return energy_j, dur_s
 
     @span("mega.billing")
@@ -553,7 +560,7 @@ class _JaxBulk:
         n = len(self._ekey)
         tier_names = sorted(set(tiers)) if tiers else ["on_demand"]
         if n == 0:
-            z = np.zeros((self.n_dev, 3))
+            z = np.zeros((self.n_dev, self.n_state))
             return (z, z.copy(), [0.0] * self.n_dev, [],
                     {t: 0.0 for t in tier_names})
         keys_np = np.asarray(self._ekey, dtype=np.int32)
@@ -593,7 +600,7 @@ class _JaxBulk:
         kvs[len(tabs):] = kvs[0]
         cums[len(tabs):] = cums[0]
         pers[len(tabs):] = pers[0]
-        g_np = gidx_dev[keys_np // 3]
+        g_np = gidx_dev[keys_np // self.n_state]
         # hourly-bin geometry + straddle pairs, exactly the unfused
         # decomposition (_finalize_carbon) but over raw log entries --
         # a device's entries are disjoint in time, so the pair count
@@ -630,9 +637,10 @@ class _JaxBulk:
                 jnp.asarray(pseg), jnp.asarray(pk), jnp.asarray(pwp),
                 jnp.asarray(kts), jnp.asarray(kvs), jnp.asarray(cums),
                 jnp.asarray(pers), jnp.asarray(tbr),
-                n_dev=self.n_dev, nb=nb, n_tier=len(tier_names))
-            energy_j = np.asarray(ej).reshape(self.n_dev, 3)
-            dur_s = np.asarray(ds).reshape(self.n_dev, 3)
+                n_dev=self.n_dev, nb=nb, n_tier=len(tier_names),
+                n_state=self.n_state)
+            energy_j = np.asarray(ej).reshape(self.n_dev, self.n_state)
+            dur_s = np.asarray(ds).reshape(self.n_dev, self.n_state)
             cums_np = np.asarray(cums_nb)
             timeline = [(min((j + 1) * bin_s, end), float(cums_np[j]))
                         for j in range(nb)]

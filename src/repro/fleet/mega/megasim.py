@@ -34,14 +34,28 @@ docs/ARCHITECTURE.md): on the pinned 10-model x 6-GPU seed-100 day,
 EXACTLY and total/per-state Wh to float-summation precision (pinned in
 ``tests/test_mega.py`` far inside the issue's 1e-3 relative budget).
 
-Scope: the fast path covers the paper's evaluation convention --
-warm-first routing, zero service time, no consolidator/autoscaler, and
-constant-timeout eviction policies (AlwaysOn / FixedTTL / Breakeven /
-CarbonBreakeven on a flat trace...).  Anything else raises
-``MegaUnsupportedError`` so callers fall back to ``run_fleet`` instead
-of silently diverging; the probe is behavioural (timeout sampled at
-several instants, arrival hook checked for statefulness), not a class
-allowlist.
+Scope: warm-first routing, no consolidator/autoscaler, no drawn
+preemption, and constant-timeout eviction policies (AlwaysOn / FixedTTL
+/ Breakeven / CarbonBreakeven on a flat trace...), under either
+
+  * the paper's evaluation convention, zero service time -- the warm
+    runs and load absorbs above; or
+  * real service time -- ``RooflineServiceTime``, or a positive
+    ``ConstantServiceTime`` -- with ``max_batch`` decode slots per
+    replica, ``run_fleet``'s semantics: a request's service is frozen
+    at its admission occupancy (one table per (model, SKU), built
+    once), requests that find every slot full wait FIFO, a busy or
+    waited-on replica cannot be evicted and re-arms its idle timeout at
+    its last completion, loads overlap serving, and a busy device draws
+    its idle-or-loading base plus ``busy x (P_active(0.6) - P_ctx)``
+    (the ACTIVE state).  Here every arrival and every completion is a
+    heap event: the warm-run claim assumes instantaneous hits, so it is
+    not taken.
+
+Anything else raises ``MegaUnsupportedError`` so callers fall back to
+``run_fleet`` instead of silently diverging; the probe is behavioural
+(timeout sampled at several instants, arrival hook checked for
+statefulness), not a class allowlist.
 """
 from __future__ import annotations
 
@@ -68,13 +82,18 @@ from repro.fleet.mega.spans import Recorder, recording, span
 from repro.fleet.pricing import (device_tier_map, price_fleet,
                                  tier_billed_seconds)
 from repro.fleet.router import WarmFirstRouter
-from repro.serving.service_model import ConstantServiceTime
+from repro.serving.service_model import (ConstantServiceTime,
+                                         RooflineServiceTime)
 
-# compact power-state codes for the three states a non-gated zero-service
-# run can occupy; indices double as wire names via _STATE_KEYS
-_BARE, _PARKED, _LOADING = 0, 1, 2
+# compact power-state codes: the first three are all a non-gated
+# zero-service run can occupy, ACTIVE (busy decode slots) joins them
+# when requests take service time; indices double as wire names via
+# _STATE_KEYS
+_BARE, _PARKED, _LOADING, _ACTIVE = 0, 1, 2, 3
 _STATE_KEYS = (PowerState.BARE.value, PowerState.CTX_IDLE.value,
-               PowerState.LOADING.value)
+               PowerState.LOADING.value, PowerState.ACTIVE.value)
+# run_fleet's utilization of a busy decode slot (Cluster.sync_power)
+_SERVICE_UTIL = 0.6
 
 # heap phases at equal timestamps, matching run_fleet's ordering
 # (completions < arrivals) plus evictions AFTER everything -- the event
@@ -86,9 +105,28 @@ _PROBE_TIMES = (0.0, 12345.678, 67801.25)
 
 
 class MegaUnsupportedError(ValueError):
-    """The scenario needs dynamics outside run_mega's vectorized scope
-    (stateful policies, service time, consolidation, autoscaling, or a
-    non-warm-first router).  Fall back to ``fleetsim.run_fleet``."""
+    """The scenario needs dynamics outside run_mega's scope (stateful
+    policies, a service-time model other than ``RooflineServiceTime`` or
+    ``ConstantServiceTime``, consolidation, autoscaling, drawn spot
+    preemption, or a non-warm-first router).  Fall back to
+    ``fleetsim.run_fleet``."""
+
+
+def _takes_service(svc, max_batch: int) -> bool:
+    """Whether requests take service time (the slot path) or not (the
+    zero-service path); raises for a service model out of scope."""
+    if isinstance(svc, ConstantServiceTime) and svc.service_s == 0.0:
+        return False
+    if not (isinstance(svc, RooflineServiceTime)
+            or (isinstance(svc, ConstantServiceTime)
+                and svc.service_s > 0.0)):
+        raise MegaUnsupportedError(
+            "run_mega supports zero service time, RooflineServiceTime and "
+            f"a positive ConstantServiceTime (got "
+            f"{getattr(svc, 'name', svc)!r} service)")
+    if max_batch < 1:
+        raise ValueError("need at least one decode slot per model")
+    return True
 
 
 def _probe_constant_timeout(policy) -> float:
@@ -130,7 +168,8 @@ def _probe_constant_timeout(policy) -> float:
 class _Rep:
     """One (device, model) replica: the ManagedModel fields the mega
     dynamics need."""
-    __slots__ = ("resident", "loading", "evict_at", "gen", "vram", "pos")
+    __slots__ = ("resident", "loading", "evict_at", "gen", "vram", "pos",
+                 "busy", "q")
 
     def __init__(self, vram: float, pos: int):
         self.resident = False
@@ -139,6 +178,11 @@ class _Rep:
         self.gen = 0            # bumped on every (re)arm/evict: stale
         self.vram = vram        # eviction events carry the gen they saw
         self.pos = pos          # registration index on its device
+        # service path only: busy decode slots and the FIFO of waiting
+        # arrival times (for a slot or for the load); both pin the
+        # replica, as run_fleet's pins do
+        self.busy = 0
+        self.q: deque = deque()
 
     def __repr__(self):  # pragma: no cover - debug aid
         return (f"_Rep(res={self.resident}, load={self.loading}, "
@@ -256,8 +300,8 @@ class _Fin:
 
     def __init__(self, energy_j, dur_s, waits, carbon_dev, carbon_timeline,
                  tier_billed_s=None):
-        self.energy_j = energy_j           # [N][3] joules per state
-        self.dur_s = dur_s                 # [N][3] seconds per state
+        self.energy_j = energy_j           # [N][S] joules per state
+        self.dur_s = dur_s                 # [N][S] seconds per state
         self.waits = waits                 # per-request waits, any order
         self.carbon_dev = carbon_dev       # [N] kgCO2e
         self.carbon_timeline = carbon_timeline
@@ -288,9 +332,9 @@ class _NumpyBulk:
     name = "numpy"
     wants_tables = False
 
-    def __init__(self, n_dev: int):
-        self.energy_j = [[0.0, 0.0, 0.0] for _ in range(n_dev)]
-        self.dur_s = [[0.0, 0.0, 0.0] for _ in range(n_dev)]
+    def __init__(self, n_dev: int, n_state: int):
+        self.energy_j = [[0.0] * n_state for _ in range(n_dev)]
+        self.dur_s = [[0.0] * n_state for _ in range(n_dev)]
         self.waits: List[float] = []
         self.in_loop = {"biggap_s": 0.0, "billing_s": 0.0}
 
@@ -320,6 +364,11 @@ class _NumpyBulk:
 
     def wait_one(self, ms: _Stream, d: int, t: float) -> None:
         ms.waiters.setdefault(d, []).append(t)
+
+    def wait(self, w: float) -> None:
+        """One admitted request's wait (the service path bills each
+        admission from a replica's queue as it happens)."""
+        self.waits.append(w)
 
     def waiter_count(self, ms: _Stream, d: int) -> int:
         return len(ms.waiters.get(d, ()))
@@ -372,7 +421,9 @@ def _phase_timings(rec: Recorder, in_loop: Dict[str, float]
     ``bulk_call_s`` and the bulk phases' host side in ``bulk_host_s``;
     ``mega.prepare`` + ``mega.finalize`` is ``bulk_host_s`` +
     ``bulk_call_s`` + the compiles inside the calls, which are all of
-    ``compile_s``."""
+    ``compile_s``.  A run whose requests take service time adds
+    ``serve_s``, the host seconds of its service path inside the event
+    loop (completions, admissions, the slot queues)."""
     w = rec.wall
     calls = [s for s in rec.spans if s.name.endswith(".call")]
     call_s = sum((s.wall for s in calls), 0.0)
@@ -387,6 +438,8 @@ def _phase_timings(rec: Recorder, in_loop: Dict[str, float]
              compile_s=rec.compile_s,
              bulk_call_s=call_s - sum((s.compile_s for s in calls), 0.0),
              bulk_host_s=w("mega.prepare") + w("mega.finalize") - call_s)
+    if "serve_s" in in_loop:
+        t["serve_s"] = in_loop["serve_s"]
     return t
 
 
@@ -410,7 +463,10 @@ def run_mega(scenario: FleetScenario, *,
     relative (pinned in tests).  ``FleetResult.phase_timings`` reports
     wall seconds per phase and ``FleetResult.counters`` the programs
     lowered and loaded, for either backend (``spans.py``,
-    docs/SCALE.md).
+    docs/SCALE.md); a run with service time also reports ``serve_s``
+    and the counters ``serve.admissions``, ``serve.slot_waits``
+    (admissions from a full pool's queue at a completion) and
+    ``serve.completions``.
     """
     with recording("mega.run") as rec:
         res, in_loop = _run_mega(scenario, compute_bound, backend, rec)
@@ -448,10 +504,7 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
     if sc.autoscaler is not None:
         raise MegaUnsupportedError("run_mega does not support autoscaling")
     svc = sc.resolved_service_model()
-    if not (isinstance(svc, ConstantServiceTime) and svc.service_s == 0.0):
-        raise MegaUnsupportedError(
-            "run_mega supports the zero-service-time convention only "
-            f"(got {getattr(svc, 'name', svc)!r})")
+    svc_on = _takes_service(svc, sc.max_batch)
     if sc.preemptions is not None and sc.preemptions.draw(
             sc.devices, sc.device_tiers(), sc.horizon_s):
         # guard on the DRAW, not the model: an all-on-demand plan under
@@ -492,8 +545,8 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
     state = [_BARE] * N
     watts = [p_bare[d] for d in range(N)]
     since = [0.0] * N
-    bulk = _Bulk(N)
-    touched = [[False, False, False] for _ in range(N)]
+    bulk = _Bulk(N, 4 if svc_on else 3)
+    touched = [[False] * 4 for _ in range(N)]
     key_order: List[List[int]] = [[] for _ in range(N)]
     segs: List[List[Tuple[float, float, float]]] = [[] for _ in range(N)]
     res_count = [0] * N
@@ -568,6 +621,25 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
             _per_sku[key] = got
         return got
 
+    # ---- service path: per-(model, SKU) service tables, slot counts ------
+    mb = sc.max_batch
+    inc = [d.profile.active_power_w(_SERVICE_UTIL) - d.profile.p_ctx_w
+           for d in devs]       # watts each busy decode slot adds
+    dev_busy = [0] * N
+    _svc_tab: Dict[Tuple[str, str], Tuple[float, ...]] = {}
+
+    def svc_table(mid: str, d: int) -> Tuple[float, ...]:
+        key = (mid, sku_of[d])
+        got = _svc_tab.get(key)
+        if got is None:
+            got = svc.table(specs[mid], devs[d], mb)
+            if min(got) <= 0.0:
+                raise MegaUnsupportedError(
+                    f"service model gives {min(got)!r} s for {mid!r} on "
+                    f"{sku_of[d]!r}; run_mega serves positive times only")
+            _svc_tab[key] = got
+        return got
+
     # ---- streams, replicas, heap -----------------------------------------
     streams: Dict[str, _Stream] = {}
     for fm in sc.models:
@@ -575,7 +647,10 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
                                                       horizon)
         streams[fm.spec.model_id] = _Stream(fm.spec.model_id, a,
                                             shared_biggap)
-    if bulk.wants_tables:
+    # the big-gap tables serve the warm-run claim, which the service
+    # path does not take
+    tables = bulk.wants_tables and not svc_on
+    if tables:
         # candidate constant timeouts per stream: one probe per (model,
         # SKU present).  A probe failure is skipped, NOT raised -- the
         # numpy path probes lazily on first routing, so scope rejection
@@ -678,7 +753,7 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         log_replicas(ms, t)
         if res_count[d] == 0 and state[d] == _PARKED:
             _trans(d, t, _BARE, p_bare[d])
-        if ms.ptr < ms.n and not ms.suspended:
+        if not svc_on and ms.ptr < ms.n and not ms.suspended:
             push_arr(ms)        # stream continues cold (or on other replicas)
 
     def make_room(d: int, mid_new: str, t: float) -> None:
@@ -692,8 +767,10 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         # the event loop scans its models dict (registration order) and
         # stable-sorts by deadline -- reproduce that from the small active
         # set: registration order first, then a stable deadline sort
+        # (a replica with busy slots or waiters is pinned, never a victim)
         victims = sorted((m for m in act[d]
-                          if m != mid_new and reps[(d, m)].resident),
+                          if m != mid_new and reps[(d, m)].resident
+                          and not reps[(d, m)].busy and not reps[(d, m)].q),
                          key=lambda m: reps[(d, m)].pos)
         victims.sort(key=lambda m: cur_evict_at(d, m, t))
         for m in victims:
@@ -724,15 +801,16 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         occ[d] += 1
         recompute_vused(d)
         loader = _loader_T(ms.mid, d)[0]
-        _trans(d, t, _LOADING, loader.p_load_w)
+        if not svc_on:          # the service path recomposes (compose)
+            _trans(d, t, _LOADING, loader.p_load_w)
         t_done = t + loader.t_load_s
         push(t_done, _P_DONE, (d, ms.mid))
         n_live += 1
         # the only replica coming up: every arrival before t_done routes
         # warm-first to this loading replica and waits -- absorb them in
         # one slice instead of one heap event each
-        if (not ms.res and ms.loading == {d} and not ms.queued
-                and ms.ptr < ms.n):
+        if (not svc_on and not ms.res and ms.loading == {d}
+                and not ms.queued and ms.ptr < ms.n):
             k = int(np.searchsorted(ms.arr, t_done, "left"))
             if k > ms.ptr:
                 bulk.absorb(ms, d, ms.ptr, k, t_done)
@@ -793,6 +871,9 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         res_count[d] += 1
         recompute_vused(d)
         d_cold[d] += 1
+        if svc_on:
+            land_svc(t, d, ms, rep)
+            return
         _trans(d, t, _PARKED, p_park[d])
         if ms.run_active:       # defensive: a run elsewhere cannot coexist
             nonlocal n_zero     # with a load in mega scope, but commit it
@@ -849,6 +930,122 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         if ms.ptr < ms.n and not ms.suspended:
             push_arr(ms)
 
+    # ---- service path: slots, FIFO queues, completions on the heap -------
+    # run_fleet's DeviceRuntime semantics, replica by replica: a request
+    # pins its replica from arrival to completion (busy slot or queue
+    # entry), the idle timeout re-arms when the last pin goes, and the
+    # device's watts are recomposed after every event that touches it.
+    n_adm = n_slot_waits = n_done = 0
+    serve_s = 0.0
+
+    def compose(d: int, t: float) -> None:
+        """Cluster.sync_power: the idle-or-loading base plus one active
+        increment per busy slot; a transition only where it changes."""
+        b = dev_busy[d]
+        lmid = inflight[d]
+        if b:
+            base = (_loader_T(lmid, d)[0].p_load_w if lmid is not None
+                    else p_park[d])
+            ns, w = _ACTIVE, base + b * inc[d]
+        elif lmid is not None:
+            ns, w = _LOADING, _loader_T(lmid, d)[0].p_load_w
+        elif res_count[d]:
+            ns, w = _PARKED, p_park[d]
+        else:
+            ns, w = _BARE, p_bare[d]
+        if ns != state[d] or w != watts[d]:
+            _trans(d, t, ns, w)
+
+    def admit(d: int, mid: str, rep: _Rep, t: float) -> None:
+        """Start one request now in a free slot of a resident replica:
+        its service is frozen at the occupancy it is admitted at."""
+        nonlocal n_live, n_adm
+        b = rep.busy
+        rep.busy = b + 1
+        dev_busy[d] += 1
+        d_reqs[d] += 1
+        n_adm += 1
+        push(t + svc_table(mid, d)[b], _P_DONE, (d, mid, rep))
+        n_live += 1
+
+    def admit_waiters(d: int, mid: str, rep: _Rep, t: float) -> int:
+        """Admit queued requests, oldest first, into free slots."""
+        n = 0
+        while rep.q and rep.busy < mb:
+            bulk.wait(t - rep.q.popleft())
+            admit(d, mid, rep, t)
+            n += 1
+        return n
+
+    def pin(rep: _Rep) -> None:
+        rep.gen += 1            # the armed idle timeout no longer fires
+        rep.evict_at = math.inf
+
+    def land_svc(t: float, d: int, ms: _Stream, rep: _Rep) -> None:
+        """A load landed: waiters fill the slots, the rest stay queued."""
+        nonlocal serve_s
+        t0 = time.perf_counter()
+        if rep.q:
+            pin(rep)
+        else:
+            arm(d, ms.mid, t)
+        admit_waiters(d, ms.mid, rep, t)
+        serve_s += time.perf_counter() - t0
+        log_replicas(ms, t)
+        pump(d, t)
+        compose(d, t)
+
+    def on_arrival_svc(t: float, mid: str, idx: int, ev: int) -> None:
+        nonlocal n_zero, serve_s
+        ms = streams[mid]
+        if ev != ms.ev or idx != ms.ptr:
+            return
+        ms.ptr += 1
+        locs = ms.res | ms.loading
+        if locs:
+            # warm-first: fewest waiting, then busy slots (a mid-load
+            # replica counts as a full pool), then lowest index
+            if len(locs) == 1:
+                d = next(iter(locs))
+            else:
+                d = min(locs, key=lambda x: (
+                    len(reps[(x, mid)].q),
+                    reps[(x, mid)].busy + (0 if x in ms.res else mb), x))
+            rep = reps[(d, mid)]
+        else:
+            d = least_loaded(mid)
+            rep = get_rep(d, mid)
+        pin(rep)
+        if rep.resident and rep.busy < mb:
+            t0 = time.perf_counter()
+            admit(d, mid, rep, t)
+            n_zero += 1
+            serve_s += time.perf_counter() - t0
+        else:
+            rep.q.append(t)
+            if not rep.resident and not rep.loading \
+                    and mid not in dq_set[d]:
+                dq_set[d].add(mid)
+                dq[d].append(mid)
+                ms.queued.add(d)
+                pump(d, t)
+        compose(d, t)
+        if ms.ptr < ms.n:
+            push_arr(ms)
+
+    def on_serve_done(t: float, d: int, mid: str, rep: _Rep) -> None:
+        """A completion frees one slot: the oldest waiter takes it, or,
+        with nothing left in flight or queued, the idle timeout arms."""
+        nonlocal n_done, n_slot_waits
+        rep.busy -= 1
+        dev_busy[d] -= 1
+        n_done += 1
+        if rep.q:
+            n_slot_waits += admit_waiters(d, mid, rep, t)
+        elif not rep.busy:
+            arm(d, mid, t)
+        compose(d, t)
+
     # ---- prewarm (run_fleet's Table-6 warm-start convention) --------------
     idx_of = {did: i for i, did in enumerate(dids)}
     for fm in sc.models:
@@ -879,7 +1076,7 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
     for fm in sc.models:        # timeline origin, including zero-replica
         ms = streams[fm.spec.model_id]
         replica_log[ms.mid].append((0.0, len(ms.res)))
-    if bulk.wants_tables:
+    if tables:
         rec.phase("mega.prepare")
         bulk.prepare(streams, stream_Ts)
     rec.phase("mega.event_loop")
@@ -887,7 +1084,7 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         ms = streams[fm.spec.model_id]
         if ms.n == 0:
             continue
-        if ms.res:
+        if ms.res and not svc_on:
             continue_stream(ms)
         else:
             push_arr(ms)
@@ -915,13 +1112,22 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         n_live -= 1
         if phase == _P_ARR:
             mid, idx, ev = payload
-            on_arrival(t, mid, idx, ev)
+            if svc_on:
+                on_arrival_svc(t, mid, idx, ev)
+            else:
+                on_arrival(t, mid, idx, ev)
+        elif len(payload) == 3:             # a serve completion
+            last_done_t = max(last_done_t, t)
+            t0 = time.perf_counter()
+            on_serve_done(t, *payload)
+            serve_s += time.perf_counter() - t0
         else:
             d, mid = payload
             last_done_t = max(last_done_t, t)
             on_load_done(t, d, mid)
 
-    # arrivals all land before the horizon; only a load can overshoot it
+    # arrivals all land before the horizon; only a load or a completion
+    # can overshoot it
     final_clock = max(horizon, last_done_t)
     for t, d, mid, gen in deferred:
         rep = reps.get((d, mid))
@@ -942,6 +1148,15 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
             raise RuntimeError(
                 f"mega invariant violated: stream {ms.mid!r} left "
                 f"{ms.n - ms.ptr} arrivals unserved")
+    if svc_on:
+        if n_done != n_adm or any(r.q or r.busy for r in reps.values()):
+            raise RuntimeError(
+                "mega invariant violated: requests left queued or in "
+                "service at the end of the day")
+        bulk.in_loop["serve_s"] = serve_s
+        rec.counters.update({"serve.admissions": n_adm,
+                             "serve.slot_waits": n_slot_waits,
+                             "serve.completions": n_done})
     for d in range(N):
         _trans(d, final_clock, state[d], watts[d])   # totals() flush
     rec.phase(None)

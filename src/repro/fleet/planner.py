@@ -4,8 +4,8 @@ The simulator meters one configuration at a time; the planner turns it
 into a capacity-planning tool.  ``plan_fleet`` sweeps a grid of plans --
 fleet composition / purchase-tier specs, routing policies, spot
 preemption rates -- runs each through the cheapest engine that can
-replay it (the compiled ``run_mega`` backends for warm-first
-zero-service plans, the event loop for everything else), and reduces
+replay it (the compiled ``run_mega`` backends for warm-first plans
+in their scope, the event loop for everything else), and reduces
 the sweep to the set of plans no other plan beats on ALL of
 
     (cost_usd, energy_wh, carbon_kg, p99_added_latency_s)
@@ -255,8 +255,8 @@ def _scenario_for(base: FleetScenario, fleet: str, router: str,
 def _evaluate(sc: FleetScenario, backend: str) -> Tuple[object, str]:
     """Run one plan through the cheapest capable engine: the compiled
     mega backend when the plan fits its scope, the event loop when it
-    does not (stateful routing, service time, consolidation,
-    autoscaling, or actual preemption faults)."""
+    does not (stateful routing, a service-time model outside its
+    scope, consolidation, autoscaling, or actual preemption faults)."""
     from repro.fleet.mega.megasim import MegaUnsupportedError, run_mega
     try:
         return (run_mega(sc, compute_bound=False, backend=backend),

@@ -32,7 +32,7 @@ published single-request H100 decode rates for that class
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional
+from typing import Optional, Tuple
 
 GB = 1024 ** 3
 
@@ -90,6 +90,14 @@ class ServiceTimeModel:
         (the request itself included).  ``spec`` is a FleetModelSpec-like
         record; ``device`` a DeviceInstance-like (``.profile``/``.sku``)."""
         raise NotImplementedError
+
+    def table(self, spec, device, max_batch: int) -> Tuple[float, ...]:
+        """Service seconds at admission occupancy 1..``max_batch``
+        (entry ``b`` is occupancy ``b + 1``).  A request's service time
+        is frozen at the occupancy it was admitted at, so one table per
+        (model, device type), built once, prices every admission."""
+        return tuple(self.request_service_s(spec, device, b)
+                     for b in range(1, max_batch + 1))
 
 
 @dataclasses.dataclass(frozen=True)

@@ -165,9 +165,22 @@ class TestScopeGuards:
                                           seed=PIN_SEED))
 
     def test_nonzero_service_time_rejected(self):
+        # the service path takes RooflineServiceTime and a positive
+        # ConstantServiceTime (tests/test_mega_service.py); service time
+        # from any other model, or a negative one, still falls back
+        from repro.serving.service_model import (ConstantServiceTime,
+                                                 ServiceTimeModel)
+
+        class PerSlot(ServiceTimeModel):
+            name = "per-slot"
+
+            def request_service_s(self, spec, device, batch):
+                return 2.0 * batch
+
         sc = mixed_fleet_scenario(Breakeven, "warm-first", seed=PIN_SEED)
-        with pytest.raises(MegaUnsupportedError, match="service"):
-            run_mega(dataclasses.replace(sc, service_s=2.0))
+        for svc in (PerSlot(), ConstantServiceTime(-2.0)):
+            with pytest.raises(MegaUnsupportedError, match="service"):
+                run_mega(dataclasses.replace(sc, service_model=svc))
 
     def test_autoscaler_rejected(self):
         sc = mixed_fleet_scenario(Breakeven, "warm-first", seed=PIN_SEED)

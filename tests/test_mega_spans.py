@@ -292,3 +292,33 @@ def test_numpy_backend_records_spans_without_jax(monkeypatch):
     assert pt["compile_s"] == 0.0 and pt["bulk_call_s"] == 0.0
     assert pt["bulk_host_s"] == pytest.approx(rec.wall("mega.finalize"))
     assert pt["bulk_scan_s"] >= pt["carbon_s"] > 0.0
+
+
+@pytest.mark.parametrize("backend", ["numpy", "jax"])
+def test_service_counters_and_serve_s(monkeypatch, backend):
+    """A day whose requests take service time counts every admission and
+    completion, counts as slot waits only admissions that had waited,
+    and books its service path in ``serve_s`` -- with no span per
+    event: two days of different sizes open the same spans."""
+    from repro.serving.service_model import (RequestShape,
+                                             RooflineServiceTime)
+    svc = RooflineServiceTime(RequestShape(1024, 256))
+    runs = []
+    for seed, rate in ((7, 300.0), (8, 900.0)):
+        tr = flash_crowd(n_routes=6, fleet="h100+a100+l40s", seed=seed,
+                         horizon_s=2 * 3600.0, base_rate_hr=rate,
+                         spike_x=10.0, spike_start_s=1800.0)
+        runs.append(_recorded(monkeypatch, tr.to_scenario(
+            Breakeven, service_model=svc, max_batch=2), backend))
+    (small, rec_small), (big, rec_big) = runs
+    assert big.requests > 2 * small.requests
+    for res in (small, big):
+        ct, pt = res.counters, res.phase_timings
+        waits = int((np.asarray(res.latencies_s) > 0.0).sum())
+        assert ct["serve.admissions"] == res.requests
+        assert ct["serve.completions"] == res.requests
+        assert 0 < ct["serve.slot_waits"] <= waits
+        assert 0.0 <= pt["serve_s"] <= pt["event_loop_s"]
+    assert [s.name for s in rec_small.spans] == \
+        [s.name for s in rec_big.spans]
+    assert "mega.prepare" not in [s.name for s in rec_big.spans]
