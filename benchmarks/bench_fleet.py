@@ -413,7 +413,7 @@ def _run_megax_bench(fast: bool, seed: int, tag: str) -> None:
     tests/test_mega.py), so the rows isolate the
     BULK-SCAN phases -- big-gap scans, deferred billing, energy
     segment-sums, and the carbon trapezoid integral -- which is where
-    the jit-compiled array programs (and the segment_trapz kernel) do
+    the jit-compiled array programs (and the fused_meter kernel) do
     their work.  Benched on a solar-duck carbon trace: time-varying
     intensity is the paper's carbon-aware setting, and it is exactly
     where the numpy path pays a per-segment Python integral.  The

@@ -81,7 +81,7 @@ def main() -> None:
     # Price the flash-crowd day against a shaped carbon trace -- the
     # setting where the numpy bulk path pays a per-segment Python
     # integral and the jax backend's compiled programs (including the
-    # kernels/segment_trapz carbon kernel) earn their keep.
+    # kernels/segment_trapz metering kernel) earn their keep.
     ct = make_trace("solar-duck", 0.39)
     trace = flash_crowd(n_routes=600, fleet=FLEET, seed=SEED,
                         base_rate_hr=130.0)

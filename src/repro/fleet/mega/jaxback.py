@@ -21,16 +21,16 @@ programs behind the ``_NumpyBulk`` seam:
     prefix sums, indexed into the stacked stream arrays, which are
     padded to their power-of-two bucket like the records) and the wait
     of each request is one vectorized subtract.
-  * **energy accounting** -- each power-state transition appends
-    ``(device*S + state, dt, watts)`` (``S`` power states: 3, or 4
-    when requests take service time); per-(device, state) joules and
-    seconds are two ``jax.ops.segment_sum`` calls at finalize.
-  * **carbon integration** -- the power-timeline x ``CarbonTrace``
-    trapezoid integral runs through the ``kernels/segment_trapz``
-    ``fused_meter`` Pallas kernel (compiled on a TPU, interpreted
-    elsewhere, see ``kernels/ops.py``), with per-device attribution one
-    segment-sum away; the hourly cumulative timeline adds the partial
-    integrals of the segments that straddle each bin boundary.
+  * **metering** -- each power-state transition appends
+    ``(device*S + state, a, b, dt, watts)`` to the charge log (``S``
+    power states: 3, or 4 when requests take service time);
+    ``finalize`` reduces the whole log in ONE compiled program around
+    the ``kernels/segment_trapz`` ``fused_meter`` Pallas kernel
+    (compiled on a TPU, interpreted elsewhere, see ``kernels/ops.py``):
+    per-(device, state) joules and seconds, per-device carbon against
+    each device's zone trace, per-tier billed seconds, and the hourly
+    cumulative carbon timeline, whose bins add the partial integrals
+    of the entries that straddle each boundary.
 
 Everything outside the kernel is float64 (the fleet accounting
 convention) via the ``jax.enable_x64`` scope, which is thread-local and
@@ -50,11 +50,9 @@ from __future__ import annotations
 
 import array
 import functools
-import itertools
 import math
-import os
 import time
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -69,12 +67,6 @@ from repro.fleet.mega.traces import FleetTrace, RouteTrace, _route_plan
 from repro.kernels import ops
 
 _J_PER_KWH = 3.6e6
-
-# Fused metering (kernels/ops.fused_meter): energy segment-sums, carbon
-# integrals, and per-tier billed seconds in ONE pass over the charge
-# log instead of three.  Module-level so tests can monkeypatch it; each
-# _JaxBulk snapshots the flag at construction.
-FUSED = os.environ.get("REPRO_MEGA_FUSED", "1") != "0"
 
 
 def _pow2(n: int, lo: int = 256) -> int:
@@ -137,76 +129,13 @@ def _bill_gather(flat: jnp.ndarray, off: jnp.ndarray, sid: jnp.ndarray,
     return t[r] - flat[pos]
 
 
-@functools.partial(jax.jit, static_argnames=("num",))
-def _energy_segsum(keys: jnp.ndarray, dt: jnp.ndarray, pw: jnp.ndarray, *,
-                   num: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Per-(device, state) joules and seconds from the transition log
-    (keys = device*S + state; pad rows carry dt = 0)."""
-    return (jax.ops.segment_sum(dt * pw, keys, num_segments=num),
-            jax.ops.segment_sum(dt, keys, num_segments=num))
-
-
-def _prefix_fn(kt: jnp.ndarray, kv: jnp.ndarray, cum: jnp.ndarray,
-               period: float) -> Callable[[jnp.ndarray], jnp.ndarray]:
-    """F(t) = integral of the periodic piecewise-linear intensity over
-    [0, t] from the extended knot tables (``CarbonTrace`` internals) --
-    the same closed form as ``kernels/ref.segment_trapz_ref``."""
-    total = cum[kt.shape[0] - 1]
-
-    def F(t):
-        k = jnp.floor(t / period)
-        p = t - k * period
-        j = jnp.clip(jnp.searchsorted(kt, p, side="right") - 1,
-                     0, kt.shape[0] - 2)
-        span = kt[j + 1] - kt[j]
-        dt = p - kt[j]
-        v_p = kv[j] + (kv[j + 1] - kv[j]) * dt / jnp.where(span > 0, span,
-                                                           1.0)
-        return k * total + cum[j] + dt * (kv[j] + v_p) * 0.5
-
-    return F
-
-
-@functools.partial(jax.jit, static_argnames=("period", "n_dev", "nb"))
-def _carbon_fused(a, b, w, dev, bucket, pseg, pk, pw, kt, kv, cum, tbr, *,
-                  period: float, n_dev: int, nb: int
-                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """kgCO2e per device AND the cumulative hourly timeline in one pass.
-
-    Per device: the segment_trapz kernel over every metered power
-    segment, attributed by one segment-sum (pad rows carry w = 0).
-
-    Timeline: the cumulative emission at boundary t is
-    ``sum_i w_i * (F(min(b_i, t)) - F(min(a_i, t)))`` -- but evaluating
-    F at every (segment, boundary) pair is an [nb, N] traversal.
-    Instead, split by how a segment meets a boundary: segments ENDING
-    at or before t contribute their whole (already-computed) integral
-    -- a segment-sum into the bin of ``b`` plus a tiny cumsum over
-    bins -- and only segments STRADDLING t (``a < t < b``; at most one
-    per device per boundary, precomputed host-side as (pseg, pk)
-    pairs) need a partial ``w * (F(t) - F(a))``.  Exact, and the pair
-    set is ~devices x boundaries, thousands of terms instead of
-    boundaries x segments millions."""
-    per_seg = ops.segment_trapz(a, b, w, kt, kv, cum, period=period)
-    per_dev = jax.ops.segment_sum(per_seg, dev,
-                                  num_segments=n_dev) / _J_PER_KWH
-    full = jnp.cumsum(jax.ops.segment_sum(per_seg, bucket,
-                                          num_segments=nb))
-    if nb > 1:
-        F = _prefix_fn(kt, kv, cum, period)
-        corr = jax.ops.segment_sum(pw * (F(tbr)[pk] - F(a)[pseg]), pk,
-                                   num_segments=nb - 1)
-        full = full.at[:nb - 1].add(corr)
-    return per_dev, full / _J_PER_KWH
-
-
 def _prefix_at(kt: jnp.ndarray, kv: jnp.ndarray, cum: jnp.ndarray,
                per: jnp.ndarray, g: jnp.ndarray, t: jnp.ndarray
                ) -> jnp.ndarray:
     """``F_g(t)`` per point for stacked trace tables: kt/kv/cum [G, K]
     (rows padded by repeating the last knot), per [G], g and t [P] ->
-    [P].  The per-row twin of ``_prefix_fn`` (same closed form,
-    compare-and-sum lookup instead of a shared searchsorted)."""
+    [P].  The closed form of ``kernels/ref.segment_trapz_ref``'s
+    prefix, with a compare-and-sum knot lookup per row."""
     ktg, kvg, cumg, pg = kt[g], kv[g], cum[g], per[g]
     k = jnp.floor(t / pg)
     p = t - k * pg
@@ -231,16 +160,17 @@ def _meter_fused(keys, a, b, dt, pw, g, bucket, tdev, pseg, pk, pwp,
     """The whole metering reduction in one compiled program fed by ONE
     metering pass (``ops.fused_meter``) over the raw charge log:
 
-      * per-(device, state) joules/seconds -- same ``segment_sum`` of
-        the same f64 ``w * dt`` products as ``_energy_segsum``, so the
-        energy/billing numbers (and the 0.0-USD engine anchors built
-        on them) are bit-identical to the unfused path;
-      * per-device carbon + the hourly cumulative timeline -- same
-        end-bin + straddle-correction decomposition as
-        ``_carbon_fused``, but over raw log entries (uncoalesced) and
-        with every zone's trace in one stacked-table launch instead of
-        one compiled call per zone group; the straddle input
-        ``F_g(a)`` is evaluated in f64 at the straddle pairs only;
+      * per-(device, state) joules/seconds -- ``segment_sum`` of the
+        pass's f64 ``w * dt`` and ``dt`` lanes;
+      * per-device carbon + the hourly cumulative timeline -- each
+        entry's whole integral lands in the bin of its END (a
+        segment-sum plus a cumsum over bins), and only entries
+        STRADDLING a boundary ``t`` (``a < t < b``, precomputed host
+        side as (pseg, pk) pairs) add a partial ``w * (F_g(t) -
+        F_g(a))``, evaluated in f64 at those pairs only.  A device's
+        entries are disjoint in time, so the pairs number at most
+        devices x boundaries, not boundaries x entries; every zone's
+        trace rides one stacked-table launch;
       * per-tier billed seconds -- a third segment-sum of the SAME
         seconds lane (in mega scope every metered state is
         powered-on, so raw seconds == billed seconds).
@@ -268,9 +198,9 @@ def _meter_fused(keys, a, b, dt, pw, g, bucket, tdev, pseg, pk, pwp,
 class _JaxBulk:
     """Drop-in for ``megasim._NumpyBulk`` that records the bulk work
     during the event loop and retires it compiled at finalize.  See the
-    module docstring for the four phases.  Timing: spans for the
-    finalize phases (``mega.billing``, and ``mega.meter`` or
-    ``mega.energy`` and ``mega.carbon``) and for each compiled call
+    module docstring for the three phases.  Timing: spans for the
+    finalize phases (``mega.billing`` and ``mega.meter``) and for each
+    compiled call
     (``mega.<program>.call``, from the ``jnp.asarray`` of its inputs to
     its results on the host); the run claims the event loop makes
     accumulate in ``in_loop``, as on the numpy backend."""
@@ -282,18 +212,16 @@ class _JaxBulk:
         self.n_dev = n_dev
         self.n_state = n_state      # power-state codes a device can hold
         self.in_loop = {"biggap_s": 0.0, "billing_s": 0.0}
-        # transition log (energy) and billing records, appended by the
-        # event loop, reduced at finalize (array.array: appends like a
-        # list, converts to ndarray as a buffer view instead of a
-        # million-element Python float walk)
+        # charge log (key, metered seconds, watts, absolute bounds) and
+        # billing records, appended by the event loop, reduced at
+        # finalize (array.array: appends like a list, converts to
+        # ndarray as a buffer view instead of a million-element Python
+        # float walk)
         self._ekey = array.array("i")
         self._edt = array.array("d")
         self._epw = array.array("d")
-        # absolute segment bounds, only consumed by the fused pass
-        # (the unfused carbon path reads the coalesced `segs` lists)
         self._ea = array.array("d")
         self._eb = array.array("d")
-        self.fused = FUSED
         self._bill: List[Tuple[int, int, int, float]] = []
         self._scalar_waits: List[float] = []
         self._sid: Dict[str, int] = {}
@@ -419,35 +347,15 @@ class _JaxBulk:
     def finalize(self, segs, fleet_segments, trace: CarbonTrace,
                  horizon: float, dev_traces=None,
                  tiers=None) -> "megasim._Fin":
+        """``segs`` and ``fleet_segments`` are the numpy backend's
+        inputs; this backend meters its own charge log."""
         with jax.enable_x64(True):
-            if self.fused:
-                (energy_j, dur_s, carbon_dev, timeline,
-                 tier_billed) = self._finalize_fused(trace, horizon,
-                                                     dev_traces, tiers)
-                waits = self._finalize_billing()
-            else:
-                energy_j, dur_s = self._finalize_energy()
-                waits = self._finalize_billing()
-                carbon_dev, timeline = self._finalize_carbon(
-                    segs, fleet_segments, trace, horizon, dev_traces)
-                tier_billed = None
+            (energy_j, dur_s, carbon_dev, timeline,
+             tier_billed) = self._finalize_meter(trace, horizon,
+                                                 dev_traces, tiers)
+            waits = self._finalize_billing()
         return megasim._Fin(energy_j, dur_s, waits, carbon_dev, timeline,
                             tier_billed)
-
-    @span("mega.energy")
-    def _finalize_energy(self):
-        n = len(self._ekey)
-        m = _pow2(n)
-        keys = _pad(np.asarray(self._ekey, dtype=np.int32), m, 0)
-        dt = _pad(np.asarray(self._edt, dtype=np.float64), m)
-        pw = _pad(np.asarray(self._epw, dtype=np.float64), m)
-        with span("mega.energy.call"):
-            ej, ds = _energy_segsum(jnp.asarray(keys), jnp.asarray(dt),
-                                    jnp.asarray(pw),
-                                    num=self.n_dev * self.n_state)
-            energy_j = np.asarray(ej).reshape(self.n_dev, self.n_state)
-            dur_s = np.asarray(ds).reshape(self.n_dev, self.n_state)
-        return energy_j, dur_s
 
     @span("mega.billing")
     def _finalize_billing(self) -> np.ndarray:
@@ -468,88 +376,8 @@ class _JaxBulk:
                 jnp.asarray(tt), total_pad=_pow2(total)))
         return np.concatenate([w[:total], scalar])
 
-    @span("mega.carbon")
-    def _finalize_carbon(self, segs, fleet_segments, trace: CarbonTrace,
-                         horizon: float, dev_traces=None):
-        n = len(fleet_segments)
-        if n == 0:
-            return [0.0] * self.n_dev, []
-        # hourly timeline, numpy-semantics bins: they cover
-        # max(horizon, last segment end), the last bin absorbing any
-        # overshoot.  Bin geometry is GLOBAL (all zones share the sim
-        # clock) even when devices integrate against different traces.
-        bin_s = 3600.0
-        end = max(horizon, max(s[-1][1] for s in segs if s))
-        nb = max(int(math.ceil(end / bin_s - 1e-12)), 1)
-        tbr = bin_s * np.arange(1, nb)               # interior boundaries
-        # partition devices by their zone's trace object: one fused
-        # call per distinct trace, device ids group-local, timelines
-        # summed elementwise.  A single-zone fleet is one group over
-        # every device -- the exact pre-zone call.
-        if dev_traces is None or all(tr is trace for tr in dev_traces):
-            groups = [(trace, list(range(self.n_dev)))]
-        else:
-            by_trace: Dict[int, Tuple[CarbonTrace, List[int]]] = {}
-            for d, tr in enumerate(dev_traces):
-                by_trace.setdefault(id(tr), (tr, []))[1].append(d)
-            groups = list(by_trace.values())
-        per_dev_out = np.zeros(self.n_dev, dtype=np.float64)
-        cums_total = np.zeros(nb, dtype=np.float64)
-        for gtrace, gdevs in groups:
-            gsegs = [segs[d] for d in gdevs]
-            gn = sum(len(s) for s in gsegs)
-            if gn == 0:
-                continue
-            # fromiter over a flattened chain beats np.asarray on a
-            # millions-long list of 3-tuples by ~2.5x
-            seg = np.fromiter(
-                itertools.chain.from_iterable(
-                    itertools.chain.from_iterable(gsegs)),
-                dtype=np.float64, count=3 * gn).reshape(gn, 3)
-            a_np, b_np, w_np = seg[:, 0], seg[:, 1], seg[:, 2]
-            dev = np.repeat(np.arange(len(gdevs), dtype=np.int32),
-                            [len(s) for s in gsegs])
-            # host-side prep for _carbon_fused: each segment's full
-            # integral lands in the bin of its END (``bucket``), and
-            # the (segment, boundary) STRADDLE pairs -- bounded by
-            # devices x boundaries, since a device's power segments
-            # are disjoint in time -- are expanded with one
-            # repeat/cumsum.
-            k_lo = np.searchsorted(tbr, a_np, side="right")
-            bucket = np.searchsorted(tbr, b_np,
-                                     side="left").astype(np.int32)
-            cnt = np.maximum(bucket - k_lo, 0)
-            total = int(cnt.sum())
-            pcap = _pow2(total, lo=1024)
-            pseg = np.zeros(pcap, dtype=np.int32)
-            pk = np.zeros(pcap, dtype=np.int32)
-            pw = np.zeros(pcap, dtype=np.float64)    # pad pairs weigh 0
-            if total:
-                ps = np.repeat(np.arange(gn, dtype=np.int32), cnt)
-                starts = np.cumsum(cnt) - cnt
-                pseg[:total] = ps
-                pk[:total] = (np.arange(total) - starts[ps] + k_lo[ps])
-                pw[:total] = w_np[ps]
-            m = _pow2(gn)
-            with span("mega.carbon.call"):
-                per_dev, cums = _carbon_fused(
-                    jnp.asarray(_pad(a_np, m)), jnp.asarray(_pad(b_np, m)),
-                    jnp.asarray(_pad(w_np, m)),          # pad weight 0
-                    jnp.asarray(_pad(dev, m, 0)),
-                    jnp.asarray(_pad(bucket, m, 0)),
-                    jnp.asarray(pseg), jnp.asarray(pk), jnp.asarray(pw),
-                    jnp.asarray(np.asarray(gtrace._kt)),
-                    jnp.asarray(np.asarray(gtrace._kv)),
-                    jnp.asarray(np.asarray(gtrace._cum)), jnp.asarray(tbr),
-                    period=float(gtrace.period_s), n_dev=len(gdevs), nb=nb)
-                per_dev_out[gdevs] = np.asarray(per_dev)
-                cums_total += np.asarray(cums)
-        timeline = [(min((j + 1) * bin_s, end), float(cums_total[j]))
-                    for j in range(nb)]
-        return list(per_dev_out), timeline
-
     @span("mega.meter")
-    def _finalize_fused(self, trace: CarbonTrace, horizon: float,
+    def _finalize_meter(self, trace: CarbonTrace, horizon: float,
                         dev_traces=None, tiers=None):
         """Energy, durations, carbon, timeline, and per-tier billed
         seconds from ONE ``_meter_fused`` launch over the raw charge
@@ -601,10 +429,11 @@ class _JaxBulk:
         cums[len(tabs):] = cums[0]
         pers[len(tabs):] = pers[0]
         g_np = gidx_dev[keys_np // self.n_state]
-        # hourly-bin geometry + straddle pairs, exactly the unfused
-        # decomposition (_finalize_carbon) but over raw log entries --
-        # a device's entries are disjoint in time, so the pair count
-        # stays bounded by devices x boundaries
+        # hourly-bin geometry, numpy-semantics bins: they cover
+        # max(horizon, last entry end), the last bin absorbing any
+        # overshoot; then the (entry, boundary) straddle pairs, expanded
+        # with one repeat/cumsum -- a device's entries are disjoint in
+        # time, so the pair count stays bounded by devices x boundaries
         bin_s = 3600.0
         end = max(horizon, float(b_np.max()))
         nb = max(int(math.ceil(end / bin_s - 1e-12)), 1)
@@ -656,8 +485,7 @@ def compiled_program_count() -> int:
     reports the delta per sweep: shared-shape grouping shows up as a
     compile count that stays flat while the point count grows."""
     return sum(fn._cache_size() for fn in (
-        _nextbig_rows, _bill_gather, _energy_segsum, _carbon_fused,
-        _meter_fused))
+        _nextbig_rows, _bill_gather, _meter_fused))
 
 
 # ---------------------------------------------------------------------------
@@ -808,7 +636,7 @@ def run_mega_sweep(scenarios=None, *, seeds: Optional[Sequence[int]] = None,
     The points then replay through ``run_mega(backend="jax")``
     sequentially (the structural event loop is inherently serial), but
     every compiled bulk program -- nextbig scans, billing gather,
-    energy segment-sums, carbon integrals -- is shared across points
+    metering -- is shared across points
     through the power-of-two shape buckets, so the batch pays each
     compile once per bucket (and per count of hourly bins), not per
     point: point 1 is compile-bound, points 2..P run hot.

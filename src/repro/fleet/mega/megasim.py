@@ -479,9 +479,9 @@ def _phase_timings(rec: Recorder, in_loop: Dict[str, float]
 
     The five bulk keys keep their meaning on both backends: big-gap
     tables and run claims (``biggap_s``), waiter billing
-    (``billing_s``), the energy reduction (``energy_s``; on the fused
-    path the compiled metering call), the carbon integral
-    (``carbon_s``; on the fused path the metering pass's host side),
+    (``billing_s``), the energy reduction (``energy_s``; on the jax
+    backend the compiled metering call), the carbon integral
+    (``carbon_s``; on the jax backend the metering pass's host side),
     and their sum ``bulk_scan_s``.  The run claims and numpy's waiter
     billing happen inside the event loop, so ``bulk_scan_s`` overlaps
     ``event_loop_s`` by them.  The rest is wall per phase, with each
@@ -497,7 +497,7 @@ def _phase_timings(rec: Recorder, in_loop: Dict[str, float]
     call_s = sum((s.wall for s in calls), 0.0)
     t = {"biggap_s": in_loop["biggap_s"] + w("mega.prepare"),
          "billing_s": in_loop["billing_s"] + w("mega.billing"),
-         "energy_s": w("mega.energy") + w("mega.meter.call"),
+         "energy_s": w("mega.meter.call"),
          "carbon_s": (w("mega.carbon") + w("mega.meter")
                       - w("mega.meter.call"))}
     t["bulk_scan_s"] = sum(t.values())

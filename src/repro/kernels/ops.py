@@ -19,7 +19,6 @@ from repro.kernels.decode_attention import decode_attention as _decode_pl
 from repro.kernels.flash_attention import flash_attention as _flash_pl
 from repro.kernels.rglru_scan import rglru_scan as _rglru_pl
 from repro.kernels.segment_trapz import fused_meter as _fused_pl
-from repro.kernels.segment_trapz import segment_trapz as _trapz_pl
 
 
 def _interpret() -> bool:
@@ -47,24 +46,6 @@ def rglru_scan(a, b, h0, *, use_pallas: bool = True) -> jnp.ndarray:
     if use_pallas:
         return _rglru_pl(a, b, h0, interpret=_interpret())
     return ref.rglru_scan_ref(a, b, h0)
-
-
-def segment_trapz(a, b, w, kt, kv, cum, *, period: float,
-                  use_pallas: Optional[bool] = None) -> jnp.ndarray:
-    """Per-segment trapezoid integrals of a periodic piecewise-linear
-    curve (the carbon-integration primitive; see segment_trapz.py).
-
-    ``use_pallas=None`` (the default) picks the kernel on a TPU and the
-    jnp reference elsewhere.  This standalone kernel is off the fleet
-    backend's main path (``fused_meter`` is on it) and does not lower
-    for the chip: there it fails loudly instead of falling back.
-    """
-    if use_pallas is None:
-        use_pallas = not _interpret()
-    if use_pallas:
-        return _trapz_pl(a, b, w, kt, kv, cum, period=period,
-                         interpret=_interpret())
-    return ref.segment_trapz_ref(a, b, w, kt, kv, cum, period=period)
 
 
 def fused_meter(a, b, dt, w, g, kt, kv, cum, periods):

@@ -1,39 +1,26 @@
-"""Per-segment trapezoid integrals of a periodic piecewise-linear
-function, as a Pallas kernel: the carbon-integration primitive of the
-mega-simulator's jax backend (``fleet/mega/jaxback.py``).
+"""The metering kernel of the mega-simulator's jax backend
+(``fleet/mega/jaxback.py``): ``fused_meter``, one pass over the charge
+log that emits each entry's energy, seconds and carbon increment.
 
-Given a metered power timeline -- segments ``(a_i, b_i, w_i)`` with
-constant power ``w_i`` over ``[a_i, b_i]`` -- and a periodic
-piecewise-linear intensity curve ``i(t)`` described by its extended
-knots (``CarbonTrace`` internals: knot times ``kt`` covering
-``[0, period]``, knot values ``kv``, and prefix trapezoid integrals
-``cum``), compute per segment
+Each charge-log entry is a segment ``(a_i, b_i)`` at constant power
+``w_i`` metered against a periodic piecewise-linear intensity curve
+``i_g(t)`` (``CarbonTrace`` internals: knot times ``kt`` covering
+``[0, period]``, knot values ``kv``, prefix trapezoid integrals
+``cum``), one curve per zone, stacked ``[G, K]``.  The carbon
+increment is
 
-    out_i = w_i * (F(b_i) - F(a_i)),   F(t) = \\int_0^t i(u) du
+    c_i = w_i * \\int_{a_i}^{b_i} i_g(u) du
 
-exactly (trapezoids between knots, whole periods factored out) -- the
-same closed form ``CarbonTrace.integral`` evaluates one segment at a
-time in Python, across a million metered segments in one pass.
+-- the closed form ``CarbonTrace.integral`` evaluates one segment at a
+time in Python (and ``kernels/ref.segment_trapz_ref`` over a single
+table), across a million entries in one pass.
 
-The kernel is embarrassingly parallel over segments: grid over
-``BN``-sized segment blocks, the (small, <=64-knot) curve tables
-broadcast to every program.  The knot lookup is branchless -- a
-``[BN, K]`` compare-and-sum instead of a binary search -- which is the
-VPU-friendly shape (K is tiny, so the redundant compares are free
-next to the HBM stream of segment endpoints).  ``jnp.take`` gathers
-along the knot axis stay in VMEM.
-
-Numerics: runs in whatever dtype the inputs carry -- float64 under an
-``enable_x64`` scope (the fleet accounting convention, CPU/interpret).
-This standalone kernel serves the unfused finalize only; its in-kernel
-gathers and 1-D blocks do not lower for the TPU.
-
-``fused_meter`` is the main path's metering pass (one pass over the
-charge log for energy, seconds and carbon).  Its kernel is written for
-Mosaic: (rows, 128) f32 blocks, no gathers (a one-hot over the few
-trace rows, a sum over knot intervals), and f32 arithmetic arranged so
-the carbon error has a derived bound (``CARBON_REL``); everything it
-cannot do in f32 stays in f64 XLA around it.
+Whole periods are counted in f64 XLA; the in-period rest runs on the
+Pallas kernel, written for Mosaic: (rows, 128) f32 blocks, no gathers
+(a one-hot over the few trace rows, a sum over knot intervals), and f32
+arithmetic arranged so the carbon error has a derived bound
+(``CARBON_REL``); everything it cannot do in f32 stays in f64 XLA
+around it.
 """
 from __future__ import annotations
 
@@ -43,32 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-
-def _segment_trapz_kernel(a_ref, b_ref, w_ref, kt_ref, kv_ref, cum_ref,
-                          o_ref, *, period: float):
-    kt = kt_ref[...]
-    kv = kv_ref[...]
-    cum = cum_ref[...]
-    total = cum[kt.shape[0] - 1]        # integral over one full period
-
-    def prefix(t):
-        """F(t) for t >= 0: whole periods times `total` plus the
-        in-period prefix read off the knot tables."""
-        k = jnp.floor(t / period)
-        p = t - k * period
-        # branchless bisect_right(kt, p) - 1: count knots <= p
-        j = jnp.sum((kt[None, :] <= p[:, None]).astype(jnp.int32), axis=1) - 1
-        j = jnp.clip(j, 0, kt.shape[0] - 2)
-        kt_j = jnp.take(kt, j)
-        kv_j = jnp.take(kv, j)
-        span = jnp.take(kt, j + 1) - kt_j
-        dt = p - kt_j
-        v_p = kv_j + (jnp.take(kv, j + 1) - kv_j) * dt \
-            / jnp.where(span > 0, span, 1.0)
-        return k * total + jnp.take(cum, j) + dt * (kv_j + v_p) * 0.5
-
-    o_ref[...] = w_ref[...] * (prefix(b_ref[...]) - prefix(a_ref[...]))
 
 
 # Fused metering geometry: entries are laid out as (rows, 128) f32 lanes;
@@ -188,8 +149,7 @@ def fused_meter(a: jnp.ndarray, b: jnp.ndarray, dt: jnp.ndarray,
 
     Returns ``(e, s, c)``, all [N] in the inputs' dtype (f64 in the
     fleet backend): joules ``w * dt`` and seconds ``dt``, computed here
-    in XLA and bit-identical to the unfused segment-sum inputs, and the
-    carbon increment ``c = w * int_a^b i_g(u) du``.
+    in XLA, never through the f32 kernel, and the carbon increment ``c = w * int_a^b i_g(u) du``.
 
     The carbon lane splits the integral: whole periods in f64 XLA
     (``n * total_g``), the in-period rest on the Pallas kernel in f32
@@ -234,38 +194,3 @@ def fused_meter(a: jnp.ndarray, b: jnp.ndarray, dt: jnp.ndarray,
                               interpret=interpret)
     c = w * (n_per * total + i_rest.astype(w.dtype))
     return w * dt, dt, c
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("period", "bn", "interpret"))
-def segment_trapz(a: jnp.ndarray, b: jnp.ndarray, w: jnp.ndarray,
-                  kt: jnp.ndarray, kv: jnp.ndarray, cum: jnp.ndarray, *,
-                  period: float, bn: int = 512,
-                  interpret: bool = True) -> jnp.ndarray:
-    """a, b, w: [N] segment starts/ends/weights; kt, kv, cum: [K]
-    extended knot times/values/prefix integrals covering [0, period]
-    (``CarbonTrace._kt/_kv/_cum``).  Returns [N] per-segment
-    ``w * (F(b) - F(a))``; N is padded internally to a ``bn`` multiple
-    (padding contributes exact zeros via w=0)."""
-    n = a.shape[0]
-    bn = min(bn, max(n, 1))
-    pad = (-n) % bn if n else bn
-    if pad:
-        a = jnp.concatenate([a, jnp.zeros(pad, a.dtype)])
-        b = jnp.concatenate([b, jnp.zeros(pad, b.dtype)])
-        w = jnp.concatenate([w, jnp.zeros(pad, w.dtype)])
-    k = kt.shape[0]
-    grid = (a.shape[0] // bn,)
-    seg_spec = pl.BlockSpec((bn,), lambda i: (i,))
-    knot_spec = pl.BlockSpec((k,), lambda i: (0,))
-    kernel = functools.partial(_segment_trapz_kernel, period=float(period))
-    out = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[seg_spec, seg_spec, seg_spec,
-                  knot_spec, knot_spec, knot_spec],
-        out_specs=seg_spec,
-        out_shape=jax.ShapeDtypeStruct(a.shape, a.dtype),
-        interpret=interpret,
-    )(a, b, w, kt, kv, cum)
-    return out[:n]

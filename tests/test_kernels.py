@@ -101,9 +101,10 @@ def test_rglru_carries_initial_state():
 
 
 # ---------------------------------------------------------------------------
-# segment_trapz: the carbon-integration primitive of the mega-simulator's
-# jax backend (fleet/mega/jaxback.py).  Oracle chain: Pallas kernel ==
-# jnp reference == CarbonTrace.integral evaluated one segment at a time.
+# segment_trapz: single-trace carbon metering.  Oracle chain: the
+# ``fused_meter`` kernel fed one stacked trace row == the scalar-table
+# f64 reference ``segment_trapz_ref`` == CarbonTrace.integral evaluated
+# one segment at a time.
 # ---------------------------------------------------------------------------
 
 def _trace_tables(trace):
@@ -112,76 +113,6 @@ def _trace_tables(trace):
     cum = np.asarray(trace._cum)
     return kt, kv, cum
 
-
-@pytest.mark.parametrize("n", [1, 17, 512, 2001])
-@pytest.mark.parametrize("shape_name", ["solar-duck", "wind-night", "flat"])
-def test_segment_trapz_sweep(n, shape_name):
-    from repro.fleet.carbon import make_trace
-
-    trace = make_trace(shape_name, 0.39)
-    kt, kv, cum = _trace_tables(trace)
-    rng = np.random.default_rng(n)
-    # spans crossing knots, bins, midnight wrap, and multiple periods
-    a = np.sort(rng.uniform(0.0, 2.5 * trace.period_s, n))
-    b = a + rng.uniform(0.0, 4 * 3600.0, n)
-    w = rng.uniform(10.0, 700.0, n)
-    want = np.array([trace.integral(x, y) * z for x, y, z in zip(a, b, w)])
-    with jax.enable_x64(True):
-        args = [jnp.asarray(x) for x in (a, b, w, kt, kv, cum)]
-        got_pl = np.asarray(ops.segment_trapz(
-            *args, period=trace.period_s, use_pallas=True))
-        got_ref = np.asarray(ops.segment_trapz(
-            *args, period=trace.period_s, use_pallas=False))
-    np.testing.assert_allclose(got_pl, want, rtol=1e-9, atol=1e-12)
-    np.testing.assert_allclose(got_ref, want, rtol=1e-9, atol=1e-12)
-    np.testing.assert_allclose(got_pl, got_ref, rtol=1e-12, atol=0)
-
-
-def test_segment_trapz_f32_kernel_matches_ref():
-    """TPU-realistic dtype: kernel and reference agree bit-comparably
-    in f32 (no f64 on real TPU hardware)."""
-    from repro.fleet.carbon import solar_duck
-
-    trace = solar_duck(0.39)
-    kt, kv, cum = (x.astype(np.float32) for x in _trace_tables(trace))
-    rng = np.random.default_rng(0)
-    a = np.sort(rng.uniform(0, 86400.0, 700)).astype(np.float32)
-    b = a + np.float32(50.0)
-    w = np.full(700, 300.0, np.float32)
-    args = [jnp.asarray(x) for x in (a, b, w, kt, kv, cum)]
-    got = np.asarray(ops.segment_trapz(*args, period=trace.period_s,
-                                       use_pallas=True))
-    want = np.asarray(ref.segment_trapz_ref(*args, period=trace.period_s))
-    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
-
-
-def test_segment_trapz_zero_and_empty_segments():
-    from repro.fleet.carbon import solar_duck
-
-    trace = solar_duck(0.39)
-    kt, kv, cum = _trace_tables(trace)
-    with jax.enable_x64(True):
-        empty = ops.segment_trapz(
-            jnp.zeros(0), jnp.zeros(0), jnp.zeros(0),
-            jnp.asarray(kt), jnp.asarray(kv), jnp.asarray(cum),
-            period=trace.period_s)
-        point = ops.segment_trapz(
-            jnp.asarray([100.0, 7e4]), jnp.asarray([100.0, 7e4]),
-            jnp.asarray([500.0, 500.0]),
-            jnp.asarray(kt), jnp.asarray(kv), jnp.asarray(cum),
-            period=trace.period_s)
-    assert np.asarray(empty).shape == (0,)
-    np.testing.assert_allclose(np.asarray(point), 0.0, atol=1e-12)
-
-
-# ---------------------------------------------------------------------------
-# fused_meter: the metering pass behind the mega jax backend's fused
-# finalize (energy segment-sum + per-tier billed seconds + per-trace
-# carbon trapezoid).  The kernel integrates each entry's in-period span
-# in f32; oracle chain: kernel == f64 jnp reference == CarbonTrace.integral
-# per entry within the derived bound CARBON_REL, and the energy/seconds
-# outputs are BIT-identical to the unfused inputs.
-# ---------------------------------------------------------------------------
 
 def _stacked_tables(traces):
     """CarbonTrace knot tables stacked [G, K]: rows padded by repeating
@@ -197,6 +128,101 @@ def _stacked_tables(traces):
     per = np.array([t.period_s for t in traces])
     return kt, kv, cum, per
 
+
+def _single_trace_carbon(a, b, w, trace):
+    """``fused_meter``'s carbon lane for segments metered against one
+    trace (every entry in group 0 of a one-row stacked table)."""
+    tabs = _stacked_tables([trace])
+    _, _, c = ops.fused_meter(
+        *[jnp.asarray(x) for x in (a, b, b - a, w)],
+        jnp.zeros(len(a), jnp.int32), *[jnp.asarray(x) for x in tabs])
+    return np.asarray(c)
+
+
+@pytest.mark.parametrize("n", [1, 17, 512, 2001])
+@pytest.mark.parametrize("shape_name", ["solar-duck", "wind-night", "flat"])
+def test_segment_trapz_sweep(n, shape_name):
+    from repro.fleet.carbon import make_trace
+    from repro.kernels.segment_trapz import CARBON_REL
+
+    trace = make_trace(shape_name, 0.39)
+    kt, kv, cum = _trace_tables(trace)
+    rng = np.random.default_rng(n)
+    # spans crossing knots, bins, midnight wrap, and multiple periods
+    a = np.sort(rng.uniform(0.0, 2.5 * trace.period_s, n))
+    b = a + rng.uniform(0.0, 4 * 3600.0, n)
+    w = rng.uniform(10.0, 700.0, n)
+    want = np.array([trace.integral(x, y) * z for x, y, z in zip(a, b, w)])
+    with jax.enable_x64(True):
+        got_ref = np.asarray(ref.segment_trapz_ref(
+            *[jnp.asarray(x) for x in (a, b, w, kt, kv, cum)],
+            period=trace.period_s))
+        got_pl = _single_trace_carbon(a, b, w, trace)
+    np.testing.assert_allclose(got_ref, want, rtol=1e-9, atol=1e-12)
+    np.testing.assert_allclose(got_pl, want, rtol=CARBON_REL, atol=1e-12)
+
+
+def test_segment_trapz_f32_kernel_matches_ref():
+    """TPU-realistic dtype: fed f32 inputs outside any x64 scope, the
+    metering kernel returns f32 lanes -- energy and seconds the f32
+    products themselves -- and its carbon stays within CARBON_REL of
+    the f64 reference evaluated on the same f32 inputs and knots."""
+    from repro.fleet.carbon import solar_duck
+    from repro.kernels.segment_trapz import CARBON_REL
+
+    trace = solar_duck(0.39)
+    kt, kv, cum, per = (x.astype(np.float32)
+                        for x in _stacked_tables([trace]))
+    rng = np.random.default_rng(0)
+    a = np.sort(rng.uniform(0, 86400.0, 700)).astype(np.float32)
+    b = a + np.float32(50.0)
+    w = np.full(700, 300.0, np.float32)
+    e, s, c = (np.asarray(o) for o in ops.fused_meter(
+        *[jnp.asarray(x) for x in (a, b, b - a, w)],
+        jnp.zeros(700, jnp.int32),
+        *[jnp.asarray(x) for x in (kt, kv, cum, per)]))
+    assert e.dtype == s.dtype == c.dtype == np.float32
+    assert np.array_equal(e, w * (b - a)) and np.array_equal(s, b - a)
+    # the curve the kernel sees: the f32 knots, with prefix integrals
+    # rebuilt from them in f64 (the f32-rounded cum does not match them)
+    kt64, kv64 = kt[0].astype(np.float64), kv[0].astype(np.float64)
+    cum64 = np.concatenate([[0.0], np.cumsum(
+        np.diff(kt64) * (kv64[1:] + kv64[:-1]) * 0.5)])
+    with jax.enable_x64(True):
+        want = np.asarray(ref.segment_trapz_ref(
+            *[jnp.asarray(x.astype(np.float64))
+              for x in (a, b, w, kt64, kv64, cum64)],
+            period=float(per[0])))
+    np.testing.assert_allclose(c, want, rtol=CARBON_REL, atol=1e-6)
+
+
+def test_segment_trapz_zero_and_empty_segments():
+    from repro.fleet.carbon import solar_duck
+
+    trace = solar_duck(0.39)
+    kt, kv, cum = _trace_tables(trace)
+    with jax.enable_x64(True):
+        empty = ref.segment_trapz_ref(
+            jnp.zeros(0), jnp.zeros(0), jnp.zeros(0),
+            jnp.asarray(kt), jnp.asarray(kv), jnp.asarray(cum),
+            period=trace.period_s)
+        point = ref.segment_trapz_ref(
+            jnp.asarray([100.0, 7e4]), jnp.asarray([100.0, 7e4]),
+            jnp.asarray([500.0, 500.0]),
+            jnp.asarray(kt), jnp.asarray(kv), jnp.asarray(cum),
+            period=trace.period_s)
+    assert np.asarray(empty).shape == (0,)
+    np.testing.assert_allclose(np.asarray(point), 0.0, atol=1e-12)
+
+
+# ---------------------------------------------------------------------------
+# fused_meter: the metering pass behind the mega jax backend's fused
+# finalize (energy segment-sum + per-tier billed seconds + per-trace
+# carbon trapezoid).  The kernel integrates each entry's in-period span
+# in f32; oracle chain: kernel == f64 jnp reference == CarbonTrace.integral
+# per entry within the derived bound CARBON_REL, and the energy/seconds
+# outputs are exactly the f64 ``w * dt`` and ``dt``.
+# ---------------------------------------------------------------------------
 
 F32_U = 2.0 ** -24          # unit roundoff of the kernel's arithmetic
 
@@ -266,8 +292,8 @@ def test_fused_meter_sweep(n, seed):
         ref_e, ref_s, ref_c = (np.asarray(o)
                                for o in ref.fused_meter_ref(*args))
         fa = np.asarray(_prefix_at(*args[5:], args[4], args[0]))
-    # pass-through outputs: exact, not allclose -- the fused finalize's
-    # energy segment-sum must be bit-identical to the unfused path
+    # pass-through outputs: exact, not allclose -- the metering
+    # finalize's energy segment-sum never sees the f32 kernel
     assert np.array_equal(e, w * dt) and np.array_equal(e, ref_e)
     assert np.array_equal(s, dt) and np.array_equal(s, ref_s)
     np.testing.assert_allclose(c, ref_c, rtol=CARBON_REL, atol=1e-12)
@@ -338,29 +364,22 @@ def test_fused_meter_empty_and_zero_width():
 
 
 def test_fused_meter_matches_segment_trapz():
-    """The fused kernel's carbon lane reproduces the standalone
-    segment_trapz path on a single-trace workload (same integral,
-    stacked-table f32 kernel vs the scalar-table f64 reference)."""
+    """The fused kernel's carbon lane reproduces the scalar-table f64
+    reference ``segment_trapz_ref`` on a single-trace workload (same
+    integral, stacked-table f32 kernel)."""
     from repro.fleet.carbon import make_trace
     from repro.kernels.segment_trapz import CARBON_REL
 
     trace = make_trace("wind-night", 0.39)
-    kt, kv, cum, per = _stacked_tables([trace])
     rng = np.random.default_rng(3)
     n = 777
     a = np.sort(rng.uniform(0.0, 2.0 * trace.period_s, n))
     b = a + rng.uniform(0.0, 7200.0, n)
     w = rng.uniform(50.0, 400.0, n)
     with jax.enable_x64(True):
-        _, _, c = ops.fused_meter(
-            jnp.asarray(a), jnp.asarray(b), jnp.asarray(b - a),
-            jnp.asarray(w), jnp.zeros(n, jnp.int32),
-            *[jnp.asarray(x) for x in (kt, kv, cum, per)])
-        flat = ops.segment_trapz(
+        c = _single_trace_carbon(a, b, w, trace)
+        flat = ref.segment_trapz_ref(
             jnp.asarray(a), jnp.asarray(b), jnp.asarray(w),
-            jnp.asarray(np.asarray(trace._kt)),
-            jnp.asarray(np.asarray(trace._kv)),
-            jnp.asarray(np.asarray(trace._cum)),
+            *[jnp.asarray(x) for x in _trace_tables(trace)],
             period=trace.period_s)
-    np.testing.assert_allclose(np.asarray(c), np.asarray(flat),
-                               rtol=CARBON_REL, atol=0)
+    np.testing.assert_allclose(c, np.asarray(flat), rtol=CARBON_REL, atol=0)

@@ -551,10 +551,11 @@ class TestJaxBackend:
 
 
 class TestFusedFinalize:
-    """The fused metering finalize (one kernel launch for energy +
-    billed seconds + carbon) against the legacy three-pass path, on the
-    multi-trace 3-zone day with mixed purchase tiers -- the widest
-    surface the fused kernel covers."""
+    """The jax backend's metering finalize (one kernel launch for
+    energy + billed seconds + carbon) against the engines that meter
+    each quantity in its own pass -- the event loop (``run_fleet``) and
+    the numpy backend -- on the multi-trace 3-zone day with mixed
+    purchase tiers, the widest surface the metering kernel covers."""
 
     FLEET = "2xh100@DEU:spot+2xa100@USA+2xl40s@IND"
 
@@ -563,48 +564,49 @@ class TestFusedFinalize:
             Breakeven, "warm-first", fleet=self.FLEET, seed=PIN_SEED,
             horizon_s=6 * 3600.0, carbon_trace="zone")
 
-    def _toggle_pair(self, monkeypatch):
-        from repro.fleet.mega import jaxback
-        fused = run_mega(self._scenario(), backend="jax",
-                         compute_bound=False)
-        monkeypatch.setattr(jaxback, "FUSED", False)
-        unfused = run_mega(self._scenario(), backend="jax",
-                           compute_bound=False)
-        return fused, unfused
+    def _jax(self):
+        return run_mega(self._scenario(), backend="jax",
+                        compute_bound=False)
 
-    def test_fused_matches_unfused(self, monkeypatch):
-        fused, unfused = self._toggle_pair(monkeypatch)
-        # energy and state durations are pass-through lanes of the same
-        # segment-sum: BIT-identical, so the 0.0-USD anchors survive
-        assert fused.energy_wh == unfused.energy_wh
-        assert fused.cost_usd == unfused.cost_usd
-        assert fused.gpu_hours_usd == unfused.gpu_hours_usd
-        for fd, ud in zip(fused.devices, unfused.devices):
-            assert fd.energy_wh == ud.energy_wh
-            assert fd.durations_s == ud.durations_s
+    def test_fused_matches_unfused(self):
+        got, ref = self._jax(), run_fleet(self._scenario())
+        assert got.requests == ref.requests
+        assert got.cold_starts == ref.cold_starts
+        assert got.energy_wh == pytest.approx(ref.energy_wh, rel=REL)
+        for rd, gd in zip(ref.devices, got.devices):
+            assert list(gd.energy_wh) == list(rd.energy_wh)
+            for k in rd.energy_wh:
+                assert gd.energy_wh[k] == pytest.approx(
+                    rd.energy_wh[k], rel=REL, abs=1e-9)
+            for k in rd.durations_s:
+                assert gd.durations_s[k] == pytest.approx(
+                    rd.durations_s[k], rel=REL, abs=1e-6)
         # the carbon lane integrates the raw charge log in the f32
-        # kernel instead of the coalesced segments in f64: same
+        # kernel, the event loop its coalesced segments in f64: same
         # integral, within the kernel's derived bound
-        assert fused.carbon_kg == pytest.approx(unfused.carbon_kg,
-                                                rel=CARBON_REL)
-        for (t1, c1), (t2, c2) in zip(unfused.carbon_timeline,
-                                      fused.carbon_timeline):
+        assert got.carbon_kg == pytest.approx(ref.carbon_kg,
+                                              rel=CARBON_REL)
+        assert len(got.carbon_timeline) == len(ref.carbon_timeline)
+        for (t1, c1), (t2, c2) in zip(ref.carbon_timeline,
+                                      got.carbon_timeline):
             assert t2 == t1
             assert c2 == pytest.approx(c1, rel=CARBON_REL, abs=1e-12)
 
-    def test_tier_billed_seconds_all_engines_agree(self, monkeypatch):
-        fused, unfused = self._toggle_pair(monkeypatch)
+    def test_tier_billed_seconds_all_engines_agree(self):
+        got = self._jax()
+        numpy_day = run_mega(self._scenario(), backend="numpy",
+                             compute_bound=False)
         ref = run_fleet(self._scenario())
-        assert set(fused.tier_billed_s) == {"on_demand", "spot"}
-        for engine in (unfused, ref):
-            assert set(engine.tier_billed_s) == set(fused.tier_billed_s)
-            for t, s in fused.tier_billed_s.items():
+        assert set(got.tier_billed_s) == {"on_demand", "spot"}
+        for engine in (numpy_day, ref):
+            assert set(engine.tier_billed_s) == set(got.tier_billed_s)
+            for t, s in got.tier_billed_s.items():
                 assert s == pytest.approx(engine.tier_billed_s[t], rel=REL)
         # mega scope has no sleep/off states, so billed seconds per
         # tier partition the full metered time
-        total = sum(s for d in fused.devices
+        total = sum(s for d in got.devices
                     for s in d.durations_s.values())
-        assert sum(fused.tier_billed_s.values()) == pytest.approx(
+        assert sum(got.tier_billed_s.values()) == pytest.approx(
             total, rel=REL)
 
     def test_fused_matches_numpy_anchor(self):

@@ -136,26 +136,47 @@ def test_recorders_of_threads_do_not_mix():
         assert all(s.parent == 0 for s in rec.spans[1:])
 
 
-@pytest.mark.parametrize("fused", [True, False], ids=["fused", "unfused"])
-def test_bulk_split_is_exact_by_construction(monkeypatch, fused):
-    from repro.fleet.mega import jaxback
-    monkeypatch.setattr(jaxback, "FUSED", fused)
-    sc = mixed_fleet_scenario(Breakeven, "warm-first", seed=PIN_SEED)
+def _service_day():
+    """A flash-crowd day whose requests take roofline service time in
+    two decode slots a replica."""
+    from repro.serving.service_model import (RequestShape,
+                                             RooflineServiceTime)
+    return flash_crowd(n_routes=6, fleet="h100+a100+l40s", seed=7,
+                       horizon_s=2 * 3600.0, base_rate_hr=300.0,
+                       spike_x=10.0, spike_start_s=1800.0).to_scenario(
+        Breakeven, service_model=RooflineServiceTime(
+            RequestShape(1024, 256)), max_batch=2)
+
+
+@pytest.mark.parametrize("day", ["zero-service", "service"])
+def test_bulk_split_is_exact_by_construction(monkeypatch, day):
+    sc = (mixed_fleet_scenario(Breakeven, "warm-first", seed=PIN_SEED)
+          if day == "zero-service" else _service_day())
     res, rec = _recorded(monkeypatch, sc, "jax")
     pt = res.phase_timings
     calls = [s for s in rec.spans if s.name.endswith(".call")]
-    assert {s.name for s in calls} >= (
+    # one metering call a day; the service day takes no big-gap tables
+    # and makes no billing records
+    assert {s.name for s in calls} == (
         {"mega.nextbig.call", "mega.billing.call", "mega.meter.call"}
-        if fused else {"mega.nextbig.call", "mega.billing.call",
-                       "mega.energy.call", "mega.carbon.call"})
+        if day == "zero-service" else {"mega.meter.call"})
     # every compile of the run happened inside a compiled call
     assert sum(s.compile_s for s in calls) == pytest.approx(
         pt["compile_s"], rel=1e-12, abs=1e-12)
     bulk = pt["bulk_host_s"] + pt["bulk_call_s"] + pt["compile_s"]
     assert rec.wall("mega.prepare") + rec.wall("mega.finalize") == \
         pytest.approx(bulk, rel=1e-9, abs=1e-9)
+    # the metering call is energy_s, the rest of mega.meter carbon_s
+    assert pt["energy_s"] == rec.wall("mega.meter.call")
+    assert pt["energy_s"] + pt["carbon_s"] == pytest.approx(
+        rec.wall("mega.meter"), rel=1e-12, abs=1e-12)
     # bulk_scan_s also holds the run claims made inside the event loop
-    assert pt["bulk_scan_s"] - bulk >= -1e-9
+    assert pt["bulk_scan_s"] - (rec.wall("mega.prepare")
+                                + rec.wall("mega.billing")
+                                + rec.wall("mega.meter")) >= -1e-9
+    if day == "zero-service":
+        # ... which outweigh what mega.finalize does outside its spans
+        assert pt["bulk_scan_s"] - bulk >= -1e-9
     assert pt["bulk_scan_s"] == pytest.approx(
         pt["biggap_s"] + pt["billing_s"] + pt["energy_s"]
         + pt["carbon_s"], rel=1e-12)
@@ -165,8 +186,9 @@ def test_bulk_split_is_exact_by_construction(monkeypatch, fused):
         <= pt["run_s"] + 1e-9
     assert all(v >= 0.0 for v in pt.values())
     root = [s.name for s in rec.spans if s.parent == 0]
-    assert root == ["mega.scenario", "mega.prepare", "mega.event_loop",
-                    "mega.finalize", "mega.report"]
+    assert root == ["mega.scenario"] + (
+        ["mega.prepare"] if day == "zero-service" else []) + [
+        "mega.event_loop", "mega.finalize", "mega.report"]
 
 
 def test_compiles_counted_where_they_happen():
