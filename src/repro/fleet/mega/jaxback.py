@@ -21,10 +21,12 @@ programs behind the ``_NumpyBulk`` seam:
     prefix sums, indexed into the stacked stream arrays, which are
     padded to their power-of-two bucket like the records) and the wait
     of each request is one vectorized subtract.
-  * **metering** -- each power-state transition appends
-    ``(device*S + state, a, b, dt, watts)`` to the charge log (``S``
-    power states: 3, or 4 when requests take service time);
-    ``finalize`` reduces the whole log in ONE compiled program around
+  * **metering** -- each power-state transition appends one record,
+    ``(device*S + state, watts, a, b)``, to the event loop's meter log
+    (``S`` power states: 3, or 4 when requests take service time);
+    ``finalize`` builds its arrays (with ``dt = b - a``) in one numpy
+    pass, derives the coalesced power timeline from them, and reduces
+    the whole log in ONE compiled program around
     the ``kernels/segment_trapz`` ``fused_meter`` Pallas kernel
     (compiled on a TPU, interpreted elsewhere, see ``kernels/ops.py``):
     per-(device, state) joules and seconds, per-device carbon against
@@ -48,7 +50,6 @@ relative, and carbon agrees within the kernel's f32 bound
 """
 from __future__ import annotations
 
-import array
 import functools
 import math
 import time
@@ -158,7 +159,7 @@ def _meter_fused(keys, a, b, dt, pw, g, bucket, tdev, pseg, pk, pwp,
                  kts, kvs, cums, pers, tbr, *,
                  n_dev: int, nb: int, n_tier: int, n_state: int = 3):
     """The whole metering reduction in one compiled program fed by ONE
-    metering pass (``ops.fused_meter``) over the raw charge log:
+    metering pass (``ops.fused_meter``) over the raw meter log:
 
       * per-(device, state) joules/seconds -- ``segment_sum`` of the
         pass's f64 ``w * dt`` and ``dt`` lanes;
@@ -212,16 +213,8 @@ class _JaxBulk:
         self.n_dev = n_dev
         self.n_state = n_state      # power-state codes a device can hold
         self.in_loop = {"biggap_s": 0.0, "billing_s": 0.0}
-        # charge log (key, metered seconds, watts, absolute bounds) and
-        # billing records, appended by the event loop, reduced at
-        # finalize (array.array: appends like a list, converts to
-        # ndarray as a buffer view instead of a million-element Python
-        # float walk)
-        self._ekey = array.array("i")
-        self._edt = array.array("d")
-        self._epw = array.array("d")
-        self._ea = array.array("d")
-        self._eb = array.array("d")
+        # billing records, appended by the event loop, expanded at
+        # finalize
         self._bill: List[Tuple[int, int, int, float]] = []
         self._scalar_waits: List[float] = []
         self._sid: Dict[str, int] = {}
@@ -284,14 +277,6 @@ class _JaxBulk:
                     ms.biggap[("nb", T)] = nb[r]
 
     # -- event-loop hooks ----------------------------------------------------
-    def charge(self, d: int, s: int, dt: float, p: float,
-               a: float = 0.0, b: float = 0.0) -> None:
-        self._ekey.append(d * self.n_state + s)
-        self._edt.append(dt)
-        self._epw.append(p)
-        self._ea.append(a)
-        self._eb.append(b)
-
     def last_of_run(self, ms, T: float) -> int:
         t0 = time.perf_counter()
         if ms.ptr >= ms.n - 1:
@@ -344,18 +329,17 @@ class _JaxBulk:
         return ent[0]
 
     # -- finalize: the compiled bulk reductions ------------------------------
-    def finalize(self, segs, fleet_segments, trace: CarbonTrace,
+    def finalize(self, log: List[float], trace: CarbonTrace,
                  horizon: float, dev_traces=None,
                  tiers=None) -> "megasim._Fin":
-        """``segs`` and ``fleet_segments`` are the numpy backend's
-        inputs; this backend meters its own charge log."""
+        """``log`` is the event loop's meter log (``megasim._log_arrays``)."""
         with jax.enable_x64(True):
-            (energy_j, dur_s, carbon_dev, timeline,
-             tier_billed) = self._finalize_meter(trace, horizon,
-                                                 dev_traces, tiers)
+            (energy_j, dur_s, carbon_dev, timeline, tier_billed,
+             power) = self._finalize_meter(log, trace, horizon, dev_traces,
+                                           tiers)
             waits = self._finalize_billing()
         return megasim._Fin(energy_j, dur_s, waits, carbon_dev, timeline,
-                            tier_billed)
+                            power, tier_billed)
 
     @span("mega.billing")
     def _finalize_billing(self) -> np.ndarray:
@@ -377,25 +361,23 @@ class _JaxBulk:
         return np.concatenate([w[:total], scalar])
 
     @span("mega.meter")
-    def _finalize_meter(self, trace: CarbonTrace, horizon: float,
-                        dev_traces=None, tiers=None):
+    def _finalize_meter(self, log: List[float], trace: CarbonTrace,
+                        horizon: float, dev_traces=None, tiers=None):
         """Energy, durations, carbon, timeline, and per-tier billed
-        seconds from ONE ``_meter_fused`` launch over the raw charge
+        seconds from ONE ``_meter_fused`` launch over the raw meter
+        log, and the coalesced power timeline derived from the same
         log.  ``phase_timings`` books the compiled call
         (``mega.meter.call``) under ``energy_s`` and the rest of
-        ``mega.meter``, the host-side prep (table stacking, bin and
-        straddle geometry), under ``carbon_s``."""
-        n = len(self._ekey)
+        ``mega.meter``, the host-side prep (the log's arrays, the power
+        timeline, table stacking, bin and straddle geometry), under
+        ``carbon_s``."""
+        keys64, pw_np, a_np, b_np = megasim._log_arrays(log)
+        n = keys64.size
+        dt_np = b_np - a_np             # the subtraction the loop made
+        power = megasim._power_segments(keys64 // self.n_state, a_np, b_np,
+                                        pw_np, self.n_dev)[0]
+        keys_np = keys64.astype(np.int32)
         tier_names = sorted(set(tiers)) if tiers else ["on_demand"]
-        if n == 0:
-            z = np.zeros((self.n_dev, self.n_state))
-            return (z, z.copy(), [0.0] * self.n_dev, [],
-                    {t: 0.0 for t in tier_names})
-        keys_np = np.asarray(self._ekey, dtype=np.int32)
-        a_np = np.asarray(self._ea, dtype=np.float64)
-        b_np = np.asarray(self._eb, dtype=np.float64)
-        dt_np = np.asarray(self._edt, dtype=np.float64)
-        pw_np = np.asarray(self._epw, dtype=np.float64)
         # stacked knot tables: one row per distinct zone trace, K
         # padded by repeating the final knot (in-period offsets are
         # strictly below the period, so pad knots never match), G
@@ -476,7 +458,7 @@ class _JaxBulk:
             tier_billed = {t: float(v)
                            for t, v in zip(tier_names, np.asarray(tier_s))}
             per_dev = list(np.asarray(per_dev))
-        return energy_j, dur_s, per_dev, timeline, tier_billed
+        return energy_j, dur_s, per_dev, timeline, tier_billed, power
 
 
 def compiled_program_count() -> int:

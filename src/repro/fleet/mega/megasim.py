@@ -7,11 +7,12 @@ array-program form, for 500-5000-device multi-million-request days.
 completions and armed idle-timeout evictions -- and retires the
 per-request work in bulk:
 
-  * Device state lives in numpy vectors (occupied slots, VRAM, power
-    state, watts).  Least-loaded placement reads the top of a lazy heap
-    of per-device keys (occ, -free VRAM, index), pushed whenever a
+  * Device state lives in plain per-device lists (occupied slots, VRAM,
+    power state, watts).  Least-loaded placement reads the top of a lazy
+    heap of per-device keys (occ, -free VRAM, index), pushed whenever a
     device's occupancy or VRAM changes; only when that top cannot take
-    the model does it fall back to one masked scan of the vectors.
+    the model does it fall back to one masked scan over numpy views of
+    the lists.
   * A model whose stream is in the common steady state -- exactly one
     warm replica, no load in flight or queued -- enters a WARM RUN: the
     maximal prefix of its remaining arrivals whose inter-arrival gaps
@@ -28,7 +29,9 @@ per-request work in bulk:
     only at actual power CHANGES, which is precisely what the event
     loop's ``EnergyMeter`` coalesces its timeline down to -- so the
     metered power segments come out float-identical and per-state Wh
-    agrees to float-summation order.
+    agrees to float-summation order.  Each change appends one record to
+    the meter log; the backends reduce the log, and derive the
+    coalesced power timeline from it, at finalize.
 
 Correctness spine (the repo's equivalence-anchor discipline,
 docs/ARCHITECTURE.md): on the pinned 10-model x 6-GPU seed-100 day,
@@ -191,13 +194,13 @@ class _Rep:
                 f"evict_at={self.evict_at:g})")
 
 
-def _scan_least_loaded(need: float, occ: np.ndarray, vused: np.ndarray,
-                       vcap: np.ndarray, scap: np.ndarray) -> int:
-    """run_fleet's least-loaded placement over the device vectors: the
-    lexicographic argmin of (occ, -free VRAM, index) over the devices
+def _scan_least_loaded(need: float, occ, vused, vcap, scap) -> int:
+    """run_fleet's least-loaded placement over the per-device sequences:
+    the lexicographic argmin of (occ, -free VRAM, index) over the devices
     with a free slot and room for ``need`` GB, else over all devices.
-    Staged boolean masks, O(N) a call."""
-    free_v = vcap - vused
+    Staged boolean masks over numpy views, O(N) a call."""
+    occ, scap = np.asarray(occ), np.asarray(scap)
+    free_v = np.asarray(vcap) - np.asarray(vused)
     cand = np.flatnonzero((scap - occ >= 1) & (free_v >= need))
     if cand.size == 0:
         cand = np.arange(len(occ))
@@ -236,7 +239,7 @@ class _PlaceHeap:
         # vused) exactly, so ties and boundaries fall as in the scan
         k = (occ, vused - self.vcap[d], d)
         if k == self.key[d]:
-            return              # unchanged, as when a load lands
+            return              # unchanged
         self.key[d] = k
         heapq.heappush(self.heap, k)
         if len(self.heap) > self.bound:
@@ -361,18 +364,55 @@ class _Stream:
         return got
 
 
+def _log_arrays(log: List[float]) -> Tuple[np.ndarray, np.ndarray,
+                                            np.ndarray, np.ndarray]:
+    """The meter log as arrays, in log order: a flat list of records of
+    four, ``(device * S + state, watts, t0, t)``, one per power
+    transition, becomes (keys, watts, a, b)."""
+    rec = np.ascontiguousarray(
+        np.fromiter(log, dtype=np.float64, count=len(log)).reshape(-1, 4).T)
+    return rec[0].astype(np.int64), rec[1], rec[2], rec[3]
+
+
+def _power_segments(dev: np.ndarray, a: np.ndarray, b: np.ndarray,
+                    pw: np.ndarray, n_dev: int
+                    ) -> Tuple[List[Tuple[float, float, float]], np.ndarray]:
+    """The coalesced power timeline, ``FleetResult.power_timeline``, from
+    the meter log: device by device in device order, and within a device
+    in log order, every interval with ``b - a > 0``, merged into the one
+    before it when that one ends at ``a`` with the same watts -- the rule
+    the event loop's ``EnergyMeter`` coalesces by.  Returns the fleet's
+    ``(t0, t1, watts)`` tuples and ``bounds``: device ``d``'s segments
+    are ``[bounds[d]:bounds[d + 1]]``."""
+    kept = np.flatnonzero(b - a > 0.0)
+    # a stable sort of 16-bit keys is a radix sort
+    key = dev[kept].astype(np.int16 if n_dev <= 1 << 15 else np.int64)
+    kept = kept[np.argsort(key, kind="stable")]
+    dk, ak, bk, pk = dev[kept], a[kept], b[kept], pw[kept]
+    new = np.ones(kept.size, dtype=bool)
+    new[1:] = (dk[1:] != dk[:-1]) | (bk[:-1] != ak[1:]) | (pk[1:] != pk[:-1])
+    first = np.flatnonzero(new)
+    last = np.empty_like(first)
+    last[:-1] = first[1:] - 1
+    last[-1:] = kept.size - 1
+    segs = list(zip(ak[first].tolist(), bk[last].tolist(),
+                    pk[first].tolist()))
+    return segs, np.searchsorted(dk[first], np.arange(n_dev + 1))
+
+
 class _Fin:
     """What a bulk backend hands back at finalize time."""
     __slots__ = ("energy_j", "dur_s", "waits", "carbon_dev",
-                 "carbon_timeline", "tier_billed_s")
+                 "carbon_timeline", "power_timeline", "tier_billed_s")
 
     def __init__(self, energy_j, dur_s, waits, carbon_dev, carbon_timeline,
-                 tier_billed_s=None):
+                 power_timeline, tier_billed_s=None):
         self.energy_j = energy_j           # [N][S] joules per state
         self.dur_s = dur_s                 # [N][S] seconds per state
         self.waits = waits                 # per-request waits, any order
         self.carbon_dev = carbon_dev       # [N] kgCO2e
         self.carbon_timeline = carbon_timeline
+        self.power_timeline = power_timeline   # coalesced, device order
         # tier -> billed seconds when the backend fused it into the
         # metering pass; None -> run_mega re-derives it from reports
         self.tier_billed_s = tier_billed_s
@@ -383,9 +423,10 @@ class _NumpyBulk:
     the simulator shipped with (the bit-exact anchor vs ``run_fleet``).
 
     The seam: the event loop owns all STRUCTURAL state (heap, replica
-    sets, pointers) and calls the backend for every bulk operation --
-    energy charging, waiter billing, big-gap run claiming, and the
-    finalize pass (carbon integration, waits assembly).  Both backends
+    sets, pointers) and the meter log, and calls the backend for every
+    bulk operation -- waiter billing, big-gap run claiming, and the
+    finalize pass (energy buckets and power timeline from the meter
+    log, carbon integration, waits assembly).  Both backends
     see identical calls in identical order, so every control-flow
     decision (routing tie-breaks, run extents) is backend-invariant by
     construction; only the arithmetic engine differs.
@@ -401,20 +442,13 @@ class _NumpyBulk:
     wants_tables = False
 
     def __init__(self, n_dev: int, n_state: int):
-        self.energy_j = [[0.0] * n_state for _ in range(n_dev)]
-        self.dur_s = [[0.0] * n_state for _ in range(n_dev)]
+        self.n_dev = n_dev
+        self.n_state = n_state
         self.waits: List[float] = []
         self.in_loop = {"biggap_s": 0.0, "billing_s": 0.0}
 
     def prepare(self, streams, stream_Ts) -> None:
         pass
-
-    def charge(self, d: int, s: int, dt: float, p: float,
-               a: float = 0.0, b: float = 0.0) -> None:
-        # a/b (the absolute interval) only feed the jax backend's fused
-        # metering pass; the numpy buckets need just dt
-        self.energy_j[d][s] += dt * p
-        self.dur_s[d][s] += dt
 
     def last_of_run(self, ms: _Stream, T: float) -> int:
         t0 = time.perf_counter()
@@ -450,8 +484,22 @@ class _NumpyBulk:
         self.in_loop["billing_s"] += time.perf_counter() - t0
         return len(w)
 
-    def finalize(self, segs, fleet_segments, trace, horizon: float,
+    def finalize(self, log: List[float], trace, horizon: float,
                  dev_traces=None, tiers=None) -> _Fin:
+        with span("mega.meter"):
+            keys, pw, a, b = _log_arrays(log)
+            dt = b - a
+            # ufunc.at adds unbuffered, element by element in log order:
+            # each bucket is the left fold of += the event loop ran
+            energy_j = np.zeros(self.n_dev * self.n_state)
+            dur_s = np.zeros(self.n_dev * self.n_state)
+            np.add.at(energy_j, keys, dt * pw)
+            np.add.at(dur_s, keys, dt)
+            fleet_segments, bounds = _power_segments(
+                keys // self.n_state, a, b, pw, self.n_dev)
+            bounds = bounds.tolist()
+            segs = [fleet_segments[lo:hi]
+                    for lo, hi in zip(bounds[:-1], bounds[1:])]
         with span("mega.billing"):
             waits = np.asarray(self.waits, dtype=np.float64)
         with span("mega.carbon"):
@@ -470,7 +518,10 @@ class _NumpyBulk:
                 carbon_dev = [trace.carbon_for_segments(s) for s in segs]
                 timeline = carbon_timeline_kg(trace, fleet_segments,
                                               end_s=horizon)
-        return _Fin(self.energy_j, self.dur_s, waits, carbon_dev, timeline)
+        shape = (self.n_dev, self.n_state)
+        return _Fin(energy_j.reshape(shape).tolist(),
+                    dur_s.reshape(shape).tolist(), waits, carbon_dev,
+                    timeline, fleet_segments)
 
 
 def _phase_timings(rec: Recorder, in_loop: Dict[str, float]
@@ -479,14 +530,16 @@ def _phase_timings(rec: Recorder, in_loop: Dict[str, float]
 
     The five bulk keys keep their meaning on both backends: big-gap
     tables and run claims (``biggap_s``), waiter billing
-    (``billing_s``), the energy reduction (``energy_s``; on the jax
-    backend the compiled metering call), the carbon integral
-    (``carbon_s``; on the jax backend the metering pass's host side),
-    and their sum ``bulk_scan_s``.  The run claims and numpy's waiter
-    billing happen inside the event loop, so ``bulk_scan_s`` overlaps
-    ``event_loop_s`` by them.  The rest is wall per phase, with each
-    compiled call (``mega.*.call``) split into ``compile_s`` and
-    ``bulk_call_s`` and the bulk phases' host side in ``bulk_host_s``;
+    (``billing_s``), the compiled metering call (``energy_s``; 0 on the
+    numpy backend), the carbon integral and the rest of ``mega.meter``,
+    the metering pass's host side (``carbon_s``: the meter log's arrays
+    and the power timeline, and on the numpy backend its energy
+    buckets), and their sum ``bulk_scan_s``.  The run claims and
+    numpy's waiter billing happen inside the event loop, so
+    ``bulk_scan_s`` overlaps ``event_loop_s`` by them.  The rest is wall
+    per phase, with each compiled call (``mega.*.call``) split into
+    ``compile_s`` and ``bulk_call_s`` and the bulk phases' host side in
+    ``bulk_host_s``;
     ``mega.prepare`` + ``mega.finalize`` is ``bulk_host_s`` +
     ``bulk_call_s`` + the compiles inside the calls, which are all of
     ``compile_s``.  A run whose requests take service time adds
@@ -606,20 +659,24 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
     dids = sorted(by_id)
     devs = [by_id[i] for i in dids]
     N = len(devs)
-    vcap = np.array([d.sku.vram_gb for d in devs], dtype=np.float64)
-    scap = np.array([d.sku.slots for d in devs], dtype=np.int64)
-    occ = np.zeros(N, dtype=np.int64)
-    vused = np.zeros(N, dtype=np.float64)
-    place = _PlaceHeap(vcap.tolist(), scap.tolist())
+    vcap = [float(d.sku.vram_gb) for d in devs]
+    scap = [int(d.sku.slots) for d in devs]
+    occ = [0] * N
+    vused = [0.0] * N
+    place = _PlaceHeap(vcap, scap)
     p_bare = [state_power_w(d.profile, PowerState.BARE) for d in devs]
     p_park = [state_power_w(d.profile, PowerState.CTX_IDLE) for d in devs]
     state = [_BARE] * N
     watts = [p_bare[d] for d in range(N)]
     since = [0.0] * N
-    bulk = _Bulk(N, 4 if svc_on else 3)
-    touched = [[False] * 4 for _ in range(N)]
+    S = 4 if svc_on else 3      # power-state codes a device can hold
+    bulk = _Bulk(N, S)
+    # the meter log: one record of four a power transition, flat (see
+    # _log_arrays); the backends reduce it at finalize
+    meter_log: List[float] = []
+    log_rec = meter_log.extend
+    touched = [False] * (N * S)                 # by log key d * S + s
     key_order: List[List[int]] = [[] for _ in range(N)]
-    segs: List[List[Tuple[float, float, float]]] = [[] for _ in range(N)]
     res_count = [0] * N
     d_cold = [0] * N
     d_reqs = [0] * N
@@ -627,30 +684,27 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
     act: List[set] = [set() for _ in range(N)]   # currently resident|loading
 
     def _touch(d: int, s: int) -> None:
-        if not touched[d][s]:
-            touched[d][s] = True
+        k = d * S + s
+        if not touched[k]:
+            touched[k] = True
             key_order[d].append(s)
 
     def _trans(d: int, t: float, ns: int, w: float) -> None:
-        """Charge the open interval into the current state's bucket and
-        enter (ns, w) -- the EnergyMeter transition, minus the dt=0
-        flushes the event loop performs (which change no joules and
-        coalesce away in its timeline)."""
+        """Log the open interval under the current state's key and enter
+        (ns, w) -- the EnergyMeter transition, minus the dt=0 flushes
+        the event loop performs (which change no joules and coalesce
+        away in its timeline)."""
         s = state[d]
-        t0 = since[d]
-        dt = t - t0
-        p = watts[d]
-        bulk.charge(d, s, dt, p, t0, t)
-        _touch(d, s)
-        if dt > 0.0:
-            sg = segs[d]
-            if sg and sg[-1][1] == t0 and sg[-1][2] == p:
-                sg[-1] = (sg[-1][0], t, p)
-            else:
-                sg.append((t0, t, p))
+        k = d * S + s
+        log_rec((k, watts[d], since[d], t))
+        if not touched[k]:      # _touch inlined: one call fewer each
+            touched[k] = True
+            key_order[d].append(s)
         state[d] = ns
         watts[d] = w
         since[d] = t
+
+    n_sorted = 0                # VRAM sums that took the sorted walk
 
     def recompute_vused(d: int) -> None:
         """Fresh registration-order sum, so capacity comparisons see the
@@ -659,12 +713,18 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         boundary ``fits`` decision).  Walks only the currently-contributing
         replicas (``act``), sorted back into registration order -- NOT all
         models ever registered on the device, which grows toward M over a
-        long day and made this O(M * events)."""
+        long day and made this O(M * events).  One replica or none needs
+        no sort: its sum is exact in any order."""
+        nonlocal n_sorted
+        a = act[d]
+        if len(a) > 1:
+            n_sorted += 1
+            a = sorted(a, key=lambda m: reps[(d, m)].pos)
         s = 0.0
-        for m in sorted(act[d], key=lambda m: reps[(d, m)].pos):
+        for m in a:
             s += reps[(d, m)].vram
         vused[d] = s
-        place.set(d, int(occ[d]), s)
+        place.set(d, occ[d], s)
 
     # ---- per-(model, SKU) constants: loader + probed constant timeout ----
     specs = {}
@@ -933,8 +993,7 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         rep.resident = True
         ms.loading.discard(d)
         ms.res.add(d)
-        res_count[d] += 1
-        recompute_vused(d)
+        res_count[d] += 1       # occ and VRAM already count it (start_load)
         d_cold[d] += 1
         if svc_on:
             land_svc(t, d, ms, rep)
@@ -1121,12 +1180,14 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         d = idx_of[fm.spec.home]
         need = fm.spec.vram_gb
         if not (scap[d] - occ[d] >= 1 and vcap[d] - vused[d] >= need):
-            fitting = np.flatnonzero((scap - occ >= 1)
-                                     & (vcap - vused >= need))
+            occ_a = np.array(occ)
+            free_a = np.array(vcap) - np.array(vused)
+            fitting = np.flatnonzero((np.array(scap) - occ_a >= 1)
+                                     & (free_a >= need))
             if fitting.size == 0:
                 continue        # starts cold
-            free_v = vcap[fitting] - vused[fitting]
-            order = np.lexsort((fitting, -free_v, occ[fitting]))
+            free_v = free_a[fitting]
+            order = np.lexsort((fitting, -free_v, occ_a[fitting]))
             d = int(fitting[order[0]])
         rep = get_rep(d, mid)
         rep.resident = True
@@ -1223,20 +1284,18 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
                              "serve.slot_waits": n_slot_waits,
                              "serve.completions": n_done})
     rec.counters.update({"place.calls": place.calls,
-                         "place.scans": place.scans})
+                         "place.scans": place.scans,
+                         "vused.sorted": n_sorted})
     for d in range(N):
         _trans(d, final_clock, state[d], watts[d])   # totals() flush
     rec.phase(None)
 
-    # ---- bulk finalize: billing, energy buckets, carbon integration ------
-    fleet_segments: List[Tuple[float, float, float]] = []
-    for d in range(N):
-        fleet_segments.extend(segs[d])
+    # ---- bulk finalize: energy buckets and power timeline from the meter
+    # log, billing, carbon integration -------------------------------------
     dev_trace_list = [dev_traces_by_id[did] for did in dids]
     tiers_map = device_tier_map(sc.devices, sc.price_tier)
     rec.phase("mega.finalize")
-    fin = bulk.finalize(segs, fleet_segments, trace, horizon,
-                        dev_trace_list,
+    fin = bulk.finalize(meter_log, trace, horizon, dev_trace_list,
                         tiers=[tiers_map[did] for did in dids])
     energy_j = fin.energy_j
     dur_s = fin.dur_s
@@ -1305,7 +1364,7 @@ def _run_mega(sc: FleetScenario, compute_bound: bool, backend: str,
         carbon_kg_flat=kg_flat,
         carbon_trace_name=trace.name,
         carbon_timeline=fin.carbon_timeline,
-        power_timeline=fleet_segments,
+        power_timeline=fin.power_timeline,
         zone_energy_wh=zone_wh, zone_carbon_kg=zone_kg,
         latencies_s=np.sort(all_lat),
         replica_timeline={mid: list(log)

@@ -49,7 +49,7 @@ def rglru_scan(a, b, h0, *, use_pallas: bool = True) -> jnp.ndarray:
 
 
 def fused_meter(a, b, dt, w, g, kt, kv, cum, periods):
-    """Metering pass over the charge log: per-entry energy, seconds and
+    """Metering pass over the meter log: per-entry energy, seconds and
     carbon increment (see ``segment_trapz.fused_meter``).  Always the
     kernel: compiled on a TPU, interpreted elsewhere, so CPU runs check
     the arithmetic the chip runs."""

@@ -84,7 +84,7 @@ def fused_meter_ref(a: jnp.ndarray, b: jnp.ndarray, dt: jnp.ndarray,
                     w: jnp.ndarray, g: jnp.ndarray,
                     kt: jnp.ndarray, kv: jnp.ndarray, cum: jnp.ndarray,
                     periods: jnp.ndarray):
-    """Metering pass (see ``segment_trapz.fused_meter``): per charge-log
+    """Metering pass (see ``segment_trapz.fused_meter``): per meter-log
     entry emit energy ``w * dt``, seconds ``dt`` and carbon increment
     ``w * (F_g(b) - F_g(a))``.  kt, kv, cum are stacked ``[G, K]``
     extended knot tables (rows padded by repeating the last knot); g:

@@ -1,8 +1,8 @@
 """The metering kernel of the mega-simulator's jax backend
-(``fleet/mega/jaxback.py``): ``fused_meter``, one pass over the charge
+(``fleet/mega/jaxback.py``): ``fused_meter``, one pass over the meter
 log that emits each entry's energy, seconds and carbon increment.
 
-Each charge-log entry is a segment ``(a_i, b_i)`` at constant power
+Each meter-log entry is a segment ``(a_i, b_i)`` at constant power
 ``w_i`` metered against a periodic piecewise-linear intensity curve
 ``i_g(t)`` (``CarbonTrace`` internals: knot times ``kt`` covering
 ``[0, period]``, knot values ``kv``, prefix trapezoid integrals
@@ -140,7 +140,7 @@ def fused_meter(a: jnp.ndarray, b: jnp.ndarray, dt: jnp.ndarray,
                 w: jnp.ndarray, g: jnp.ndarray,
                 kt: jnp.ndarray, kv: jnp.ndarray, cum: jnp.ndarray,
                 periods: jnp.ndarray, *, interpret: bool):
-    """Metering pass over ``N`` charge-log entries.
+    """Metering pass over ``N`` meter-log entries.
 
     a, b: [N] absolute segment bounds; dt: [N] the metered interval;
     w: [N] watts; g: [N] int32 trace-group index; kt, kv, cum: [G, K]
